@@ -11,7 +11,6 @@ ref: /root/reference README.md:15-37), re-designed for TPU:
 - mixed-precision (f32 factor + f64 refine) is the native high-precision path.
 """
 
-from .util import compat_jax as _compat_jax  # noqa: F401  (installs shims)
 from .version import __version__, id, version  # noqa: F401
 from .types import Diag, Layout, Norm, Op, Side, TileKind, Uplo  # noqa: F401
 from .options import (  # noqa: F401

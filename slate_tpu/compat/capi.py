@@ -25,9 +25,8 @@ import numpy as np
 import jax
 
 if os.environ.get("SLATE_CAPI_PLATFORM"):
-    # embedding hosts cannot call jax.config themselves; the env var
-    # JAX_PLATFORMS is overridden by preinstalled accelerator plugins on
-    # some deployments, so honor an explicit knob here
+    # embedding hosts cannot call jax.config themselves, so honor an
+    # explicit knob here
     jax.config.update("jax_platforms", os.environ["SLATE_CAPI_PLATFORM"])
 # the C API traffics in doubles: without x64 JAX silently computes the
 # whole solve in f32 (this module is only imported by embedding hosts,

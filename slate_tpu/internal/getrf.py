@@ -29,14 +29,55 @@ from jax import lax
 from .trsm import tri_inv_lower, tri_inv_upper
 
 
+# XLA's TPU LU custom call holds a [W, 128] column block in scoped VMEM;
+# the chip compiler refuses it past this many rows (16384 x 128 is
+# refused, 8192 x 512 compiles — tests/test_tpu_compile.py).
+XLA_LU_MAX_ROWS = 8192
+
+
 def panel_lu(panel):
     """Partially-pivoted LU of a gathered panel [W, nb].
 
     Returns (lu, perm) with panel[perm] = L @ U (L unit lower incl. rows
-    below the square part; U upper nb x nb).
+    below the square part; U upper nb x nb).  XLA's LU up to
+    XLA_LU_MAX_ROWS rows, the recursive panel (same pivot rule) above:
+    where both compile, XLA's ran 2.5x (128 x 128) to 6.6x (8192 x 512)
+    faster on the chip (tools/panel_lu_time.py, PERF.md).
     """
+    if panel.shape[0] > XLA_LU_MAX_ROWS:
+        return panel_lu_recursive(panel)
     lu, _, perm = lax.linalg.lu(panel)
     return lu, perm
+
+
+def panel_lu_recursive(panel, base: int = 128):
+    """Partially-pivoted LU of a panel [W, w], W >= w, by recursive
+    column halving (Toledo; ref LAPACK getrf2): factor the left half,
+    permute and solve the right half against it, factor the updated
+    lower-right block.  Only the <= ``base``-wide leaves run the rank-1
+    column loop (:func:`panel_lu_threshold` at tau = 1, which is exactly
+    the max-magnitude pivot rule); everything between is MXU gemms.
+    A 128-wide leaf moves the same lane-padded bytes per step as a
+    narrower one and compiles ~5x faster at 16384 x 512.
+
+    Its solves and updates multiply at HIGHEST: with the TPU's default
+    f32 product (one bf16 pass) the factor's ||P A - L U|| / ||A|| was
+    3e-2 to 8e-2 at 512 columns (tools/panel_lu_time.py on the chip),
+    since partial pivoting's unit-lower L11 can have a large inverse."""
+    w = panel.shape[1]
+    if w <= base:
+        return panel_lu_threshold(panel, 1.0)
+    h = w // 2
+    with jax.default_matmul_precision("highest"):
+        lu1, p1 = panel_lu_recursive(panel[:, :h], base)
+        right = panel[p1, h:]
+        l11 = jnp.tril(lu1[:h], -1) + jnp.eye(h, dtype=panel.dtype)
+        u12 = tri_inv_lower(l11, unit_diag=True) @ right[:h]
+        lu2, p2 = panel_lu_recursive(right[h:] - lu1[h:] @ u12, base)
+    top = jnp.concatenate([lu1[:h], u12], axis=1)
+    bot = jnp.concatenate([lu1[h:][p2], lu2], axis=1)
+    perm = jnp.concatenate([p1[:h], p1[h:][p2]])
+    return jnp.concatenate([top, bot], axis=0), perm
 
 
 # ---- out-of-core step kernels (drivers/lu.py getrf_ooc) ----
@@ -89,12 +130,13 @@ def panel_lu_nopiv(panel):
     if _nopiv_fused_ok(panel.dtype, panel.shape[0], nb):
         from ..tune import resolve_plan
         from .pallas_lu import lu_panel_fused
-        from .potrf import _interpret
+        from ..util.runtime import pallas_interpret
         w = panel.shape[0]
         plan = resolve_plan("getrf_panel", w, "float32")
         wp = -(-w // nb) * nb
         pp = jnp.pad(panel, ((0, wp - w), (0, 0))) if wp != w else panel
-        lu = lu_panel_fused(pp, bw=plan.bw, interpret=_interpret())[:w]
+        lu = lu_panel_fused(pp, bw=plan.bw,
+                            interpret=pallas_interpret())[:w]
         return lu, jnp.arange(w)
     top = panel[:nb]
     lu_top = _lu_nopiv_square(top)
@@ -234,10 +276,10 @@ def panel_lu_tournament(panel, block_rows: int, arity: int = 2):
         if _lu_select_ok(blocks, nb):
             from ..tune import resolve_plan
             from .pallas_lu import lu_select_pallas
-            from .potrf import _interpret
+            from ..util.runtime import pallas_interpret
             bw = resolve_plan("lu_select", blocks.shape[1], "float32").bw
             take = jax.vmap(lambda b: lu_select_pallas(
-                b, bw=bw, interpret=_interpret()))(blocks)
+                b, bw=bw, interpret=pallas_interpret()))(blocks)
         else:
             _, _, pb = jax.vmap(lax.linalg.lu)(blocks)
             take = pb[:, :nb]

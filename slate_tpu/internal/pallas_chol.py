@@ -55,7 +55,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_tri import upper_tri_inv
+from .pallas_tri import PANEL_PARAMS, upper_tri_inv
 
 _HI = lax.Precision.HIGHEST
 
@@ -189,6 +189,7 @@ def chol_panel_fused(col, left, lead, bw: int = 8, interpret: bool = False):
                    jax.ShapeDtypeStruct((m, nb), col.dtype)],
         scratch_shapes=[pltpu.VMEM((nb, nb), col.dtype),
                         pltpu.VMEM((nb, nb), col.dtype)],
+        compiler_params=PANEL_PARAMS,
         interpret=interpret,
     )(col, left, lead)
     return upd, fac
@@ -219,9 +220,12 @@ def _chol_panel_batched_kernel(tiles_ref, col_ref, left_ref, lead_ref,
     @pl.when(live)
     def _update():
         # left-looking rank-k chunk: acc -= A[b, i-tile, chunk] @ lead
-        acc_ref[:] = acc_ref[:] - jnp.dot(left_ref[0], lead_ref[0],
-                                          preferred_element_type=f32,
-                                          precision=_HI)
+        # bf16 x bf16 products are exact on the MXU; HIGHEST asks for
+        # the f32 multi-pass product, which Mosaic refuses for bf16 (an
+        # explicit DEFAULT survives a caller's default_matmul_precision)
+        acc_ref[:] = acc_ref[:] - jnp.dot(
+            left_ref[0], lead_ref[0], preferred_element_type=f32,
+            precision=_HI if dt == f32 else lax.Precision.DEFAULT)
 
     @pl.when(j == kc - 1)
     def _finish():
@@ -307,6 +311,7 @@ def chol_panel_batched(col, left, lead, tiles, k: int = 0, bw: int = 8,
         ),
         out_shape=[jax.ShapeDtypeStruct((bsz, m, nb), col.dtype),
                    jax.ShapeDtypeStruct((bsz, m, nb), col.dtype)],
+        compiler_params=PANEL_PARAMS,
         interpret=interpret,
     )(tiles, col, left, lead)
     return upd, fac

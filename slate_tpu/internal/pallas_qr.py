@@ -37,6 +37,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_tri import PANEL_PARAMS
+
 _HI = lax.Precision.HIGHEST
 
 
@@ -133,6 +135,7 @@ def qr_panel_pallas(a, interpret: bool = False):
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.VMEM)],
+        compiler_params=PANEL_PARAMS,
         interpret=interpret,
     )(a)
     return packed, t

@@ -21,8 +21,16 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas import tpu as pltpu
 
 _HI = lax.Precision.HIGHEST
+# Scoped VMEM for the whole-tile panel kernels (fused/batched Cholesky
+# and LU, the Householder panel).  Their in-kernel temporaries outgrow
+# the compiler's 16 MiB default at the widths the seams admit (nb=512,
+# QR panels past 2048 x 256; lane-padded [m, 1] columns count as
+# [m, 128]).  Half of v5e's 128 MiB VMEM compiles every admitted shape
+# (tests/test_tpu_compile.py).
+PANEL_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 2 ** 20)
 
 
 def upper_tri_inv(u):

@@ -19,22 +19,11 @@ force-on/force-off override of the cached plan.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 from ..tune import resolve_plan
-
-
-@functools.lru_cache(maxsize=None)
-def _interpret() -> bool:
-    """Pallas plans run interpret=True off-TPU (same results, CPU-traced)
-    so tuned paths stay testable everywhere."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # noqa: BLE001 — no backend: interpret
-        return True
+from ..util.runtime import pallas_interpret
 
 
 def _tile_plan_ok(dtype, n: int) -> bool:
@@ -53,7 +42,8 @@ def potrf_tile(a):
     if a.ndim == 2 and _tile_plan_ok(a.dtype, n):
         from .pallas_chol import chol_tile_pallas
         plan = resolve_plan("potrf_tile", n, "float32")
-        return chol_tile_pallas(a, bw=plan.bw, interpret=_interpret())
+        return chol_tile_pallas(a, bw=plan.bw,
+                                interpret=pallas_interpret())
     return jnp.linalg.cholesky(a)
 
 
@@ -109,5 +99,5 @@ def potrf_panel_fused(col, left, lead):
         col = jnp.pad(col, ((0, mp - m), (0, 0)))
         left = jnp.pad(left, ((0, mp - m), (0, 0)))
     upd, fac = chol_panel_fused(col, left, lead, bw=plan.bw,
-                                interpret=_interpret())
+                                interpret=pallas_interpret())
     return upd[:m], fac[:m]

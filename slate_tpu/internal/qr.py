@@ -165,9 +165,11 @@ def _qr_panel_ok(dtype, mm: int, w: int) -> bool:
     """True when the tuned plan routes this panel through the Pallas
     Householder kernel (internal/pallas_qr.py): real f32, MXU-aligned
     width, and the whole [mm, w] panel + its T triangle resident in
-    VMEM (~4 MB per panel copy caps mm * w at 2^20)."""
+    the kernel's VMEM budget (pallas_tri.PANEL_PARAMS), which the chip
+    compiler meets up to mm * max(w, 256) = 2^21 (8192 x 256)."""
     if not (dtype == jnp.float32 and mm >= w and mm % 8 == 0
-            and w % 128 == 0 and 128 <= w <= 512 and mm * w <= 2 ** 20):
+            and w % 128 == 0 and 128 <= w <= 512
+            and mm * max(w, 256) <= 2 ** 21):
         return False
     from ..tune import resolve_plan
     return resolve_plan("geqrf_panel", mm, "float32").kernel == "pallas"
@@ -184,8 +186,8 @@ def geqrf_panel(a, base_w: int = 32):
     # slate-lint: disable=TRC001 -- capability probe: reads only static shape/dtype/plan, never tracer data
     if _qr_panel_ok(a.dtype, mm, w):
         from .pallas_qr import qr_panel_pallas
-        from .potrf import _interpret
-        return qr_panel_pallas(a, interpret=_interpret())
+        from ..util.runtime import pallas_interpret
+        return qr_panel_pallas(a, interpret=pallas_interpret())
     return householder_panel_blocked(a, base_w)
 
 

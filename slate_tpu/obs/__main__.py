@@ -1,10 +1,11 @@
 """CLI: aggregate slate event/bench JSONL; SLO verdicts; round compare.
 
-    python -m slate_tpu.obs events.jsonl BENCH_r07.json
+    python -m slate_tpu.obs events.jsonl BENCH_r06.json
     python -m slate_tpu.obs --json events.jsonl > summary.json
     python -m slate_tpu.obs --slo budgets.json events.jsonl
     python -m slate_tpu.obs --prom events.jsonl
-    python -m slate_tpu.obs --compare BENCH_r04.json BENCH_r05.json \
+    python -m slate_tpu.obs --compare tests/data/bench_r04_metrics.json \
+        tests/data/bench_r05_metrics.json \
         --gate 10
 
 Accepts any mix of obs event JSONL (slate-obs-v1), span JSONL,

@@ -25,9 +25,9 @@ Contract (the jaxpr-identity guarantee, tested in tests/test_obs.py):
 
 Event schema ``slate-obs-v1`` is documented in docs/OBSERVABILITY.md.
 
-This module imports only the stdlib and jax — it sits below every other
-slate_tpu package so drivers, robust/, tune/ and util/ can all hook in
-without cycles.
+This module imports only the stdlib and jax (via util/compat_jax) — it
+sits below every other slate_tpu package so drivers, robust/, tune/ and
+util/ can all hook in without cycles.
 """
 
 from __future__ import annotations
@@ -39,7 +39,7 @@ import threading
 import time
 from collections import deque
 
-import jax
+from ..util.compat_jax import tracing
 
 from . import flops as _flops
 from . import sentinel as _sentinel
@@ -248,7 +248,7 @@ def boundary_enter(op: str, args=()):
     counting those would flag the caller for retraces it never made."""
     depth = getattr(_TLS, "depth", 0)
     _TLS.depth = depth + 1
-    traced = not jax.core.trace_state_clean()
+    traced = tracing()
     if traced and depth == 0:
         shapes, dtype = _describe_args(args)
         _sentinel.record_trace(op, _signature(shapes, dtype))

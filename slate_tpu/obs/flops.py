@@ -146,12 +146,9 @@ def chip_peak(dtype=None):
     accelerator — PEAK_TABLE keyed by the jax device kind and the
     storage ``dtype`` (default bf16, the headline rate).  A dtype with
     no table entry for the chip (e.g. float64) reads None."""
+    import jax
     dt = _peak_dtype(dtype)
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:                        # no backend at all
-        return None, "cpu"
+    kind = jax.devices()[0].device_kind.lower()
     for key, peaks in PEAK_TABLE:
         if key in kind:
             return peaks.get(dt), kind

@@ -25,7 +25,7 @@ import json
 import threading
 import time
 
-import jax
+from ..util.compat_jax import tracing
 
 _TLS = threading.local()
 
@@ -48,7 +48,7 @@ class SpanRecorder:
     def enter(self, name: str):
         self._depth += 1
         return (name, time.perf_counter(), self._depth,
-                not jax.core.trace_state_clean())
+                tracing())
 
     def exit(self, token) -> None:
         name, t0, depth, traced = token

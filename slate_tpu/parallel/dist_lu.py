@@ -96,16 +96,13 @@ def _row_bundle_exchange(a_loc, out_rows, in_rows, p, r, nbundle):
     out_rows/in_rows: [nbundle] global element-row indices (padded entries
     are fixed points out==in, harmless rewrites)."""
     mtl, ntl, nb, _ = a_loc.shape
-
-    def fetch(g):
-        lt = (g // nb) // p
-        tg = g % nb
-        own = ((g // nb) % p) == r
-        row = lax.dynamic_index_in_dim(a_loc, lt, axis=0, keepdims=False)
-        row = lax.dynamic_index_in_dim(row, tg, axis=1, keepdims=False)
-        return jnp.where(own, row, jnp.zeros_like(row))   # [ntl, nb]
-
-    bundle = jax.vmap(fetch)(in_rows)            # [nbundle, ntl, nb]
+    # one gather of exactly the [ntl, nb] element rows: a vmapped
+    # dynamic index would gather each row's whole [ntl, nb, nb] tile row
+    # first (20 GiB at n=20480 on 2x2 — more than the chip holds)
+    own = ((in_rows // nb) % p) == r
+    bundle = a_loc[(in_rows // nb) // p, :, in_rows % nb, :]
+    bundle = jnp.where(own[:, None, None], bundle,
+                       jnp.zeros_like(bundle))   # [nbundle, ntl, nb]
     bundle = lax.psum(bundle, AXIS_P)
 
     def scatter(a_loc, b):

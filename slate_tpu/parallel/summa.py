@@ -37,6 +37,7 @@ from ..core.grid import AXIS_P, AXIS_Q, TILE_SPEC, Grid
 from ..internal.gemm import tile_outer_product
 from ..robust import abft as _abft
 from ..robust import faults
+from ..util.compat_jax import pvary
 from ..util.trace import span
 
 
@@ -126,8 +127,9 @@ def summa_local(a_loc, b_loc, c_loc, alpha, beta, Kt: int, p: int, q: int,
                                                       b_row[None])
         return acc, rexp, cexp
 
-    zero = (jnp.zeros_like(c_loc), jnp.zeros((mtl, ntl, mb), dt),
-            jnp.zeros((mtl, ntl, nb), dt))
+    zero = (jnp.zeros_like(c_loc),
+            pvary(jnp.zeros((mtl, ntl, mb), dt), (AXIS_P, AXIS_Q)),
+            pvary(jnp.zeros((mtl, ntl, nb), dt), (AXIS_P, AXIS_Q)))
     if la == 0:
         def body(k, carry):
             a_col, b_row = step(k)

@@ -63,6 +63,7 @@ from ..robust import certify as _cert
 from ..robust import health as _h
 from ..robust import precision as _prec
 from ..types import Uplo
+from ..util.runtime import pallas_interpret
 
 _TILE = 128
 
@@ -70,6 +71,17 @@ _TILE = 128
 # certified precision rung), f64 (vmapped XLA cores only).  Anything
 # else raises SlateUnsupportedDtypeError at the boundary.
 SERVE_DTYPES = ("float32", "bfloat16", "float64")
+
+# Products the serving programs trace without an explicit precision.  A
+# served answer is certified at 50 n eps of f32 (robust/certify.py); the
+# TPU's default f32 product (one bf16 pass) errs by ~2^-9 per operand,
+# which at serving sizes (n of a few hundred) is the same order as that
+# tolerance, and it caps iterative refinement at bf16 accuracy since the
+# residual b - A x is itself such a product.  Small problems are
+# latency-bound, so the extra MXU passes cost little.  The bf16 Pallas
+# panels pin their bf16 x bf16 dots to DEFAULT explicitly (Mosaic
+# refuses HIGHEST there).
+PRODUCT_PRECISION = "highest"
 
 
 def _tile(n: int) -> int:
@@ -108,41 +120,56 @@ def _cond_escalate(h1, x1, safe, operands, dtype):
 # ------------------------------------------------------------------- cores
 
 
-def solve_core(a: jax.Array, b: jax.Array, opts: Options | None = None):
-    """General solve A x = b on bucket-shaped dense operands.
+def _live_tol(dtype, n, bucket: int):
+    """Certificate tolerance 50 n eps at each problem's live size ``n``
+    (traced); filler slots (n = 0) and callers without sizes take the
+    bucket's."""
+    n = bucket if n is None else jnp.where(n > 0, n, bucket)
+    return _cert.tolerance(dtype, 1) * jnp.asarray(n, jnp.float32)
 
-    Fast rung: NoPiv LU — no pivot search, the serving speculation —
-    plus two sweeps of iterative refinement in the original system
-    (the ``_rbt_attempt`` recipe), demoted on pivot growth beyond
-    ``health.growth_limit`` exactly like the eager speculative path.
-    Safe rung: partial-pivot LU."""
+
+def _lu_attempt(factor, ad, bd, tol, o):
+    """One LU rung of ``solve``: factor, solve, two sweeps of iterative
+    refinement in the original system, and the residual certificate at
+    ``tol`` (the ``_rbt_attempt`` recipe).  Growth beyond
+    ``health.growth_limit`` demotes it like the eager speculative path."""
     from ..drivers import lu as _lu
-    t = _tile(a.shape[0])
+    t = _tile(ad.shape[0])
+    F, fh = factor(_mat(ad, t), o)
+    xd = _lu.getrs(F, _mat(bd, t), o).to_dense()
+    for _ in range(2):                     # r = b - A x, dx through F
+        xd = xd + _lu.getrs(F, _mat(bd - ad @ xd, t), o).to_dense()
+    cert = _cert.certify_solve(jnp.linalg.norm(ad), xd, bd, bd - ad @ xd,
+                               tol=tol, iters=2)
+    return xd, _demote(_h.merge(fh, cert, _h.from_result(xd)), ad.dtype)
+
+
+def solve_core(a: jax.Array, b: jax.Array, opts: Options | None = None,
+               n=None):
+    """General solve A x = b on bucket-shaped dense operands; ``n`` is
+    the live size the certificate is held at (the bucket's if None).
+
+    Fast rung: NoPiv LU — no pivot search, the serving speculation.
+    Safe rung: partial-pivot LU.  Both refine and certify
+    (:func:`_lu_attempt`): a NoPiv factor accurate enough to pass the
+    growth gate can still leave refinement short of the certificate."""
+    from ..drivers import lu as _lu
     o = _info(opts)
-
-    def attempt(factor, ops, ir_steps):
-        ad, bd = ops
-        F, fh = factor(_mat(ad, t), o)
-        xd = _lu.getrs(F, _mat(bd, t), o).to_dense()
-        for _ in range(ir_steps):          # r = b - A x, dx through F
-            rd = bd - ad @ xd
-            xd = xd + _lu.getrs(F, _mat(rd, t), o).to_dense()
-        h = _h.merge(fh, _h.from_result(xd))
-        return xd, _demote(h, ad.dtype)
-
-    x1, h1 = attempt(_lu.getrf_nopiv, (a, b), 2)
+    tol = _live_tol(a.dtype, n, a.shape[0])
+    x1, h1 = _lu_attempt(_lu.getrf_nopiv, a, b, tol, o)
     return _cond_escalate(h1, x1,
-                          lambda ops: attempt(_lu.getrf, ops, 0),
-                          (a, b), a.dtype)
+                          lambda ops: _lu_attempt(_lu.getrf, *ops, o),
+                          (a, b, tol), a.dtype)
 
 
 def chol_solve_core(a: jax.Array, b: jax.Array,
-                    opts: Options | None = None):
+                    opts: Options | None = None, n=None):
     """HPD solve on bucket-shaped dense operands (full symmetric ``a``).
 
     Fast rung: Cholesky — an indefinite problem NaN-fills its factor,
     reads ``nonfinite`` and escalates.  Safe rung: partial-pivot LU,
-    which solves any nonsingular Hermitian system."""
+    which solves any nonsingular Hermitian system.  ``n`` is unused: no
+    rung here carries a residual certificate."""
     from ..drivers import cholesky as _chol
     from ..drivers import lu as _lu
     t = _tile(a.shape[0])
@@ -168,12 +195,13 @@ def chol_solve_core(a: jax.Array, b: jax.Array,
 
 
 def least_squares_core(a: jax.Array, b: jax.Array,
-                       opts: Options | None = None):
+                       opts: Options | None = None, n=None):
     """Least squares min ||A x - b|| on bucket-shaped (mb, nb) operands.
 
     Fast rung: CholQR semi-normal equations — rank deficiency or squared
     conditioning fails the Gram Cholesky and escalates.  Safe rung:
-    Householder QR.  Returns x of shape (nb, kb)."""
+    Householder QR.  Returns x of shape (nb, kb).  ``n`` is unused: the
+    CholQR attempt certifies at the bucket (drivers/qr.py)."""
     from ..drivers import qr as _qr
     t = _tile(a.shape[1])
     o = _info(opts)
@@ -208,11 +236,6 @@ RAGGED_OPS = {
     "chol_solve": "batch_potrf",
     "least_squares_solve": "batch_geqrf",
 }
-
-
-def _interpret() -> bool:
-    # slate-lint: disable=TRC001 -- capability probe: backend kind is host-static, never tracer data
-    return jax.default_backend() != "tpu"
 
 
 def _ragged_plan(op: str, a: jax.Array, opts: Options | None,
@@ -259,29 +282,23 @@ def _vmap_escalate(h1, x1, safe, operands, dtype):
 
 
 def _ragged_solve(a, b, sizes, plan, opts: Options | None):
-    """solve fast rung via batch_getrf (ragged NoPiv LU + 2 IR sweeps),
-    safe rung the per-problem partial-pivot LU."""
+    """solve fast rung via batch_getrf (ragged NoPiv LU + 2 IR sweeps,
+    certified at each live size), safe rung solve_core's partial-pivot
+    LU attempt."""
     from ..drivers import lu as _lu
     from ..internal import batched as _bk
-    t = _tile(a.shape[1])
     o = _info(opts)
+    tol = _live_tol(a.dtype, sizes, a.shape[1])
     fa = _bk.batch_getrf(a, sizes, nb=plan.nb, bw=plan.bw,
-                         interpret=_interpret())
+                         interpret=pallas_interpret())
     x = _bk.batch_getrs(fa, b)
     for _ in range(2):                     # r = b - A x, dx through fa
         x = x + _bk.batch_getrs(fa, b - a @ x)
-    h1 = _h.merge(_bk.batch_lu_health(a, fa),
+    h1 = _h.merge(_bk.batch_lu_health(a, fa), _certify_batch(a, x, b, tol),
                   jax.vmap(_h.from_result)(x))
     h1 = _demote(h1, a.dtype)
-
-    def safe(ops):
-        ad, bd = ops
-        F, fh = _lu.getrf(_mat(ad, t), o)
-        xd = _lu.getrs(F, _mat(bd, t), o).to_dense()
-        h = _h.merge(fh, _h.from_result(xd))
-        return xd, _demote(h, ad.dtype)
-
-    return _vmap_escalate(h1, x, safe, (a, b), a.dtype)
+    return _vmap_escalate(h1, x, lambda ops: _lu_attempt(_lu.getrf, *ops, o),
+                          (a, b, tol), a.dtype)
 
 
 def _ragged_chol(a, b, sizes, plan, opts: Options | None):
@@ -293,7 +310,7 @@ def _ragged_chol(a, b, sizes, plan, opts: Options | None):
     t = _tile(a.shape[1])
     o = _info(opts)
     fa, counts = _bk.batch_potrf(a, sizes, nb=plan.nb, bw=plan.bw,
-                                 interpret=_interpret(),
+                                 interpret=pallas_interpret(),
                                  abft=resolve_abft(opts))
     y = lax.linalg.triangular_solve(fa, b, left_side=True, lower=True)
     x = lax.linalg.triangular_solve(fa, y, left_side=True, lower=True,
@@ -323,7 +340,7 @@ def _ragged_lstsq(a, b, sizes, plan, opts: Options | None):
     t = _tile(nb)
     o = _info(opts)
     x, packed = _bk.batch_gels(a, b, sizes, nb=plan.nb,
-                               interpret=_interpret())
+                               interpret=pallas_interpret())
 
     def hone(p, xi):
         d = jnp.abs(jnp.diagonal(p[:nb, :nb]))
@@ -348,6 +365,15 @@ RAGGED_CORES = {
 # ---------------------------------------------------------- precision rung
 
 
+def _certify_batch(a, x, b, tol):
+    """Per-problem certify_solve of a refined batch, each at its own
+    tolerance ``tol`` [B]."""
+    return jax.vmap(
+        lambda an, xi, bi, ri, ti: _cert.certify_solve(an, xi, bi, ri,
+                                                       tol=ti, iters=2)
+    )(_fro_batch(a), x, b, b - a @ x, tol)
+
+
 def _fro_batch(v):
     """Per-problem Frobenius norms of a [B, m, n] stack, f32."""
     v = _prec.promote(v)
@@ -363,7 +389,7 @@ def _bf16_chol_attempt(a, b, sizes, plan, opts: Options | None):
     al = _prec.demote(a)
     if plan is not None:
         fal, counts = _bk.batch_potrf(al, sizes, nb=plan.nb, bw=plan.bw,
-                                      interpret=_interpret(),
+                                      interpret=pallas_interpret(),
                                       abft=resolve_abft(opts))
     else:
         # bf16 factor storage emulated around the batched XLA factor
@@ -379,10 +405,7 @@ def _bf16_chol_attempt(a, b, sizes, plan, opts: Options | None):
     x = solve(b)
     for _ in range(2):                     # f32 IR against the ORIGINAL a
         x = x + solve(b - a @ x)
-    r = b - a @ x
-    cert = jax.vmap(
-        lambda an, xi, bi, ri: _cert.certify_solve(an, xi, bi, ri, iters=2)
-    )(_fro_batch(a), x, b, r)
+    cert = _certify_batch(a, x, b, _live_tol(a.dtype, sizes, a.shape[1]))
     h1 = _bk.batch_chol_health(fa)
     if counts is not None:
         h1 = h1._replace(abft_detected=counts.detected,
@@ -400,7 +423,7 @@ def _bf16_solve_attempt(a, b, sizes, plan, opts: Options | None):
     al = _prec.demote(a)
     if plan is not None:
         fal = _bk.batch_getrf(al, sizes, nb=plan.nb, bw=plan.bw,
-                              interpret=_interpret())
+                              interpret=pallas_interpret())
         getrs = lambda rhs: _bk.batch_getrs(fal, rhs)  # noqa: E731
         fh = _bk.batch_lu_health(a, _prec.promote(fal))
     else:
@@ -418,10 +441,7 @@ def _bf16_solve_attempt(a, b, sizes, plan, opts: Options | None):
     x = getrs(b)
     for _ in range(2):                     # f32 IR against the ORIGINAL a
         x = x + getrs(b - a @ x)
-    r = b - a @ x
-    cert = jax.vmap(
-        lambda an, xi, bi, ri: _cert.certify_solve(an, xi, bi, ri, iters=2)
-    )(_fro_batch(a), x, b, r)
+    cert = _certify_batch(a, x, b, _live_tol(a.dtype, sizes, a.shape[1]))
     h1 = _h.merge(fh, cert, jax.vmap(_h.from_result)(x))
     return x, _demote(h1, a.dtype)
 
@@ -437,7 +457,7 @@ def _bf16_lstsq_attempt(a, b, sizes, plan, opts: Options | None):
     al = _prec.demote(a)
     if plan is not None:
         x, packed = _bk.batch_gels(al, b, sizes, nb=plan.nb,
-                                   interpret=_interpret())
+                                   interpret=pallas_interpret())
         R = _prec.promote(packed[:, :nb, :nb])
     else:
         q, r = lax.linalg.qr(_prec.promote(al), full_matrices=False)
@@ -493,7 +513,8 @@ def _bf16_rung(op: str, a, b, sizes, opts: Options | None):
         x32, h32, _ = RAGGED_CORES[op](a, b, sizes, plan32, opts)
     else:
         core = CORES[op]
-        x32, h32, _ = jax.vmap(lambda ai, bi: core(ai, bi, opts))(a, b)
+        x32, h32, _ = jax.vmap(lambda ai, bi, si: core(ai, bi, opts, si))(
+            a, b, sizes)
     return _vmap_escalate(h1, x1, lambda ops: ops, (x32, h32), a.dtype)
 
 
@@ -505,9 +526,10 @@ def make_batched(op: str, opts: Options | None = None):
     filler slots).  At trace time `_ragged_plan` consults the tune/
     plan cache: a Pallas plan routes the fast rung through the ragged
     batched kernels (each problem computes only its own tiles), a miss
-    vmaps the per-problem cores over the full bucket — which ignore
-    ``sizes`` entirely, so both routes share one executable signature
-    and the warm server stays retrace-free whichever is picked.  ``opts``
+    vmaps the per-problem cores over the full bucket — which read
+    ``sizes`` only for solve's certificate tolerance — so both routes
+    share one executable signature and the warm server stays
+    retrace-free whichever is picked.  ``opts``
     is closed over as static configuration (it participates in the
     executable-cache fingerprint, never in the traced data).
 
@@ -521,6 +543,10 @@ def make_batched(op: str, opts: Options | None = None):
     bf16_rung = _prec.resolve_precision(opts)
 
     def fn(a, b, sizes):
+        with jax.default_matmul_precision(PRODUCT_PRECISION):
+            return _fn(a, b, sizes)
+
+    def _fn(a, b, sizes):
         dtype = _prec.normalize_dtype(a.dtype, supported=SERVE_DTYPES)
         low = dtype == _prec.LOW
         if low:
@@ -531,7 +557,9 @@ def make_batched(op: str, opts: Options | None = None):
         plan = _ragged_plan(op, a, opts)
         if plan is not None:
             return RAGGED_CORES[op](a, b, sizes, plan, opts)
-        del sizes                          # vmapped route pads to bucket
-        return jax.vmap(lambda ai, bi: core(ai, bi, opts))(a, b)
+        # the vmapped route factors the whole padded bucket; sizes only
+        # set each problem's certificate tolerance
+        return jax.vmap(lambda ai, bi, si: core(ai, bi, opts, si))(
+            a, b, sizes)
 
     return fn

@@ -65,6 +65,12 @@ class ExecutableCache:
                     "misses": self._misses,
                     "compile_ms": round(self._compile_ms, 3)}
 
+    def executables(self) -> list:
+        """The compiled executables held (inspection: chip_smoke.py reads
+        which kernels they contain)."""
+        with self._lock:
+            return list(self._exes.values())
+
     def clear(self) -> None:
         with self._lock:
             self._exes.clear()

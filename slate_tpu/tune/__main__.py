@@ -95,4 +95,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ..util.runtime import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -22,11 +22,6 @@ CANDIDATE_BW = (8, 16)
 _SEED = 0
 
 
-def _interpret() -> bool:
-    import jax
-    return jax.default_backend() != "tpu"
-
-
 def candidates(op: str, n: int, dtype: str = "float32") -> list[TilePlan]:
     """The search space for one (op, n, dtype): always the XLA fallback,
     plus every shape-legal Pallas (nb, bw) pair.  The batched ragged
@@ -66,9 +61,10 @@ def _problem(op: str, plan: TilePlan, n: int, dtype: str = "float32"):
     from ..internal.pallas_chol import chol_panel_fused, chol_tile_pallas
     from ..internal.pallas_lu import lu_panel_fused, lu_select_pallas
     from ..internal.pallas_qr import qr_panel_pallas
+    from ..util.runtime import pallas_interpret
 
     rng = np.random.default_rng(_SEED)
-    interp = _interpret()
+    interp = pallas_interpret()
     nb = min(plan.nb, n)
     pallas = plan.kernel == "pallas"
 

@@ -166,13 +166,11 @@ def validate_cache(obj) -> None:
 
 def chip_kind() -> str:
     """Cache key for the local accelerator: the device kind string
-    (e.g. 'tpu-v5-lite'), normalized; 'cpu' off-accelerator."""
-    try:
-        import jax
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", "") or dev.platform
-    except Exception:                            # uninitialized backend
-        return "cpu"
+    (e.g. 'tpu-v5-lite'), normalized; 'cpu' off-accelerator.  A backend
+    that cannot start raises here: it is never read as a CPU."""
+    import jax
+    dev = jax.devices()[0]
+    kind = getattr(dev, "device_kind", "") or dev.platform
     return str(kind).strip().lower().replace(" ", "-")
 
 

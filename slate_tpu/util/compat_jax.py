@@ -1,23 +1,16 @@
-"""jax version compatibility shims.
-
-The framework targets the stable ``jax.shard_map`` API.  On older jax
-releases (<= 0.4.x) the same function lives at
-``jax.experimental.shard_map.shard_map`` with an identical keyword
-signature (f, mesh, in_specs, out_specs); installing it under the stable
-name at import time lets every mesh path run unmodified on both.  Import
-this module before any ``jax.shard_map`` call site (slate_tpu/__init__.py
-does, first thing).
-"""
+"""The few JAX spellings the framework needs, one per function, for the
+installed JAX (0.9)."""
 
 from __future__ import annotations
 
 import jax
+from jax import lax
 
 
-def install() -> None:
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map
-        jax.shard_map = shard_map
+def tracing() -> bool:
+    """True while a JAX transformation (jit, vmap, shard_map, eval_shape)
+    is tracing on this thread."""
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
@@ -26,39 +19,11 @@ def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
     Scan carries that start as constants and become psum-replicated inside
     the loop (dist_lu's perm/min-pivot trace, dist_chol's health trace) are
     genuinely replicated but not provably so to the checker — it requires
-    exact carry-rep equality and has no join for constant reps.  The kwarg
-    spelling differs across jax versions (check_rep / check_vma), so probe
-    the signature."""
-    import inspect
-    kw = {}
-    try:
-        params = inspect.signature(jax.shard_map).parameters
-        for name in ("check_rep", "check_vma"):
-            if name in params:
-                kw[name] = False
-                break
-    except (TypeError, ValueError):  # C-accelerated / exotic signature
-        kw["check_rep"] = False
+    exact carry-rep equality and has no join for constant reps."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, **kw)
+                         out_specs=out_specs, check_vma=False)
 
 
 def pvary(x, axes):
-    """Mark ``x`` device-varying over ``axes`` for the replication checker.
-
-    Newer jax spells this ``lax.pcast(..., to="varying")`` or
-    ``lax.pvary``; on versions without the varying-manual-axes machinery
-    the annotation is a semantic no-op (identity) and the enclosing
-    shard_map must be built with :func:`shard_map_unchecked`."""
-    from jax import lax
-    try:
-        return lax.pcast(x, axes, to="varying")
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return lax.pvary(x, axes)
-    except AttributeError:
-        return x
-
-
-install()
+    """Mark ``x`` device-varying over ``axes`` for the replication checker."""
+    return lax.pcast(x, axes, to="varying")

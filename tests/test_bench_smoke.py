@@ -1,7 +1,7 @@
 """CPU smoke tests for bench.py: every metric function emits one parseable
 JSON line at toy sizes, and the SLATE_BENCH_BUDGET_S harness skips (never
-kills) metrics that would blow the budget — the whole run always exits 0
-with one line per metric (BENCH_r04 rc=1 / BENCH_r05 rc=124 regressions).
+kills) metrics that would blow the budget — every step still emits its
+line, and the run exits non-zero when any step errored or was cut.
 """
 
 import importlib.util
@@ -59,11 +59,13 @@ def test_metric_emits_json(bench, capsys, name, kwargs):
     assert line["schema"] == "slate-bench-v1"
     assert line["unit"] == "GFLOP/s"
     assert "chip" in line
-    assert isinstance(line["value"], (int, float)) and line["value"] > 0
+    # a CPU timing is no speed: check the line's structure, not its value
+    assert isinstance(line["value"], (int, float)) and line["value"] >= 0
     assert isinstance(line["vs_baseline"], (int, float))
+    assert line["flops"] > 0 and line["device_ms"] > 0
     if "abft" in name:
         assert isinstance(line["abft_overhead_pct"], (int, float))
-        assert line["plain_gflops"] > 0
+        assert isinstance(line["plain_gflops"], (int, float))
 
 
 def test_potrf_ooc_emits_gflops_and_slowdown(bench, capsys):
@@ -167,12 +169,12 @@ def test_serve_bf16_emits_both_routes_and_accept_rate(bench, capsys):
 
 
 def test_serve_bf16_skips_clean_under_budget_preemption(bench, capsys):
-    """The new metric must honor the rc=0 contract: preempted by the
-    budget pool, it reports a skipped line instead of dying."""
+    """Preempted by the budget pool, the metric reports a skipped line
+    instead of dying, and counts as a cut step."""
     failures = bench._run_isolated(
         [(bench.bench_serve_bf16,
           dict(problems=6, nrhs=2, reps=1, bucket=16))], budget_s=1e-6)
-    assert failures == 0
+    assert failures == 1
     lines = _lines(capsys)
     assert len(lines) == 1
     assert lines[0]["metric"] == "bench_serve_bf16_skipped"
@@ -262,7 +264,7 @@ def test_budget_preempts_slow_metric(bench, capsys):
     failures = bench._run_isolated([(sleepy, {})], budget_s=0.3)
     elapsed = time.monotonic() - t0
     assert elapsed < 5
-    assert failures == 0
+    assert failures == 1
     lines = _lines(capsys)
     assert len(lines) == 1
     assert lines[0]["skipped"] is True
@@ -295,7 +297,7 @@ def test_budget_skips_up_front(bench, capsys, monkeypatch):
     # pool = 2 * 30 = 60s of fake time: quick runs (clock 80 > deadline
     # 100? no: deadline = 40 + 60 = 100, check at 80), never is skipped
     failures = bench._run_isolated([(quick, {}), (never, {})], budget_s=30)
-    assert failures == 0
+    assert failures == 1
     assert ran == [1]
     lines = _lines(capsys)
     assert len(lines) == 1
@@ -311,10 +313,10 @@ def test_no_budget_is_unlimited(bench, capsys):
     assert ran == [1]
 
 
-def test_failures_are_isolated_and_main_exits_zero(bench, capsys,
-                                                   monkeypatch):
-    """A raising metric emits an error line; main() still returns 0 (the
-    r04 regression was rc=1 after isolated failures)."""
+def test_failures_are_isolated_and_main_exits_nonzero(bench, capsys,
+                                                      monkeypatch):
+    """A raising metric emits an error line, the later step still runs,
+    and main() returns 1: a run with a failed step is not a success."""
 
     def boom():
         raise RuntimeError("synthetic")
@@ -324,7 +326,7 @@ def test_failures_are_isolated_and_main_exits_zero(bench, capsys,
     monkeypatch.setattr(bench, "QUICK_STEPS",
                         [(boom, {}), (lambda: ran.append(1), {})])
     rc = bench.main()
-    assert rc == 0
+    assert rc == 1
     assert ran == [1]
     lines = _lines(capsys)
     assert len(lines) == 1                # boom's error line; the lambda
@@ -332,10 +334,10 @@ def test_failures_are_isolated_and_main_exits_zero(bench, capsys,
     assert "synthetic" in lines[0]["error"]
 
 
-def test_watchdog_fires_and_exits_zero(bench, capsys, monkeypatch):
+def test_watchdog_fires_and_exits_nonzero(bench, capsys, monkeypatch):
     """The watchdog thread escapes even a stuck C++ compile (where SIGALRM
     is queued but never delivered): past the grace deadline it emits a
-    skipped line for every step not yet done and hard-exits 0."""
+    skipped line for every step not yet done and hard-exits 1."""
     monkeypatch.setattr(bench, "_WATCHDOG_GRACE_S", 0.0)
     exited = []
     fired = time.monotonic()
@@ -354,7 +356,7 @@ def test_watchdog_fires_and_exits_zero(bench, capsys, monkeypatch):
     while not exited and time.monotonic() < deadline:
         time.sleep(0.01)
     stop.set()
-    assert exited and exited[0][0] == 0
+    assert exited and exited[0][0] == 1
     lines = _lines(capsys)
     assert len(lines) == 1                # only the NOT-done index reported
     assert lines[0]["metric"] == "stuck_skipped"

@@ -151,3 +151,25 @@ def test_grid_rank_order():
     assert g.tile_rank(0, 1) == 2
     g2 = st.Grid(2, 3, devices=jax.devices()[:6], order=st.GridOrder.Row)
     assert g2.tile_rank(0, 1) == 1 and g2.tile_rank(1, 0) == 3
+
+
+def test_compile_cache_is_placed_from_outside_or_fixed(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache is
+    the fixed .jax_cache/ at the checkout root, never a temporary name."""
+    from slate_tpu.util import runtime
+    set_to = []
+    monkeypatch.setattr(runtime.jax.config, "update",
+                        lambda k, v: set_to.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert runtime.use_compile_cache() == "/elsewhere/cache"
+    assert set_to == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = runtime.use_compile_cache()
+    assert path == str(runtime.CHECKOUT / ".jax_cache")
+    assert set_to == [("jax_compilation_cache_dir", path)]
+    assert (runtime.CHECKOUT / "chip_smoke.py").exists()
+
+
+def test_pallas_interprets_off_tpu_only():
+    from slate_tpu.util import runtime
+    assert runtime.pallas_interpret() == (jax.default_backend() != "tpu")

@@ -158,3 +158,27 @@ def test_gesv_under_jit(rng):
 
     x = solve(A, B).to_numpy()
     assert np.linalg.norm(a @ x - b) / np.linalg.norm(b) < 1e-12
+
+
+@pytest.mark.parametrize("W,w,dtype", [(300, 128, np.float64),
+                                       (257, 40, np.float32),
+                                       (96, 64, np.complex128)])
+def test_recursive_panel_matches_partial_pivoting(rng, W, w, dtype):
+    """The recursive panel (taken past XLA_LU_MAX_ROWS) factors P A = L U
+    with LAPACK's pivots on real panels; complex panels pivot on |z|
+    rather than |re| + |im|, so those are held to the factorization."""
+    from jax import lax
+
+    from slate_tpu.internal.getrf import panel_lu_recursive
+    a = rng.standard_normal((W, w)).astype(dtype)
+    if np.iscomplexobj(a):
+        a = a + 1j * rng.standard_normal((W, w))
+    lu, perm = panel_lu_recursive(jnp.asarray(a), base=16)
+    lu, perm = np.asarray(lu), np.asarray(perm)
+    L = np.tril(lu, -1)[:, :w] + np.eye(W, w)
+    U = np.triu(lu[:w])
+    tol = 1e-4 if dtype == np.float32 else 1e-11
+    np.testing.assert_allclose(L @ U, a[perm], atol=tol * w)
+    if not np.iscomplexobj(a):
+        _, _, ref = lax.linalg.lu(jnp.asarray(a))
+        assert (perm == np.asarray(ref)).all()

@@ -10,7 +10,7 @@ The load-bearing guarantees:
   only: traced frames never sync, and the jaxpr is byte-identical with
   timing on or off (the jaxpr-identity guarantee extends to timing);
 - the perf-regression sentinel (``--compare``) classifies the real
-  checked-in rounds BENCH_r04 -> r05 (all shared metrics improved,
+  rounds r04 -> r05 (tests/data: their shared metric lines; all improved,
   exit 0) and gates the reverse diff (exit 1);
 - SLO budgets evaluate against the serving aggregate with metric-owned
   directions, fail LOUDLY on missing data, and export Prometheus text;
@@ -33,6 +33,7 @@ from slate_tpu.obs import events as obs_events
 from slate_tpu.obs import flops, metrics, slo
 
 REPO = Path(__file__).resolve().parent.parent
+DATA = Path(__file__).resolve().parent / "data"
 
 
 def _hpd(rng, n=32):
@@ -279,11 +280,12 @@ def test_compare_lower_better_metrics(tmp_path):
 
 
 def test_cli_compare_real_rounds_r04_to_r05(capsys):
-    """The acceptance drill: diff the checked-in pre-schema wrapper
-    rounds.  Every shared metric improved r04 -> r05, so the gate passes;
-    the reverse diff is 3 gated regressions and exit 1."""
-    r04 = str(REPO / "BENCH_r04.json")
-    r05 = str(REPO / "BENCH_r05.json")
+    """The acceptance drill: diff two pre-schema wrapper rounds holding
+    the shared metric lines of the driver's r04 and r05 chip records.
+    Every shared metric improved r04 -> r05, so the gate passes; the
+    reverse diff is 3 gated regressions and exit 1."""
+    r04 = str(DATA / "bench_r04_metrics.json")
+    r05 = str(DATA / "bench_r05_metrics.json")
     assert obs_cli.main(["--compare", r04, r05]) == 0
     out = capsys.readouterr().out
     assert "gemm_n4096_gflops_per_chip" in out
@@ -295,8 +297,8 @@ def test_cli_compare_real_rounds_r04_to_r05(capsys):
 
 
 def test_cli_compare_json_and_missing_file(tmp_path, capsys):
-    r04 = str(REPO / "BENCH_r04.json")
-    r05 = str(REPO / "BENCH_r05.json")
+    r04 = str(DATA / "bench_r04_metrics.json")
+    r05 = str(DATA / "bench_r05_metrics.json")
     assert obs_cli.main(["--json", "--compare", r04, r05]) == 0
     doc = json.loads(capsys.readouterr().out)
     shared = {r["metric"] for r in doc["rows"]}

@@ -10,8 +10,8 @@ Answers the round-5 profile questions (VERDICT weak #1/#2/#8):
     lu(512x512) single + vmapped over 32 chunks
   - one full potrf step (panel + trsm + trailing syrk) at k=0 vs its gemm
 
-Timing follows bench.py's tunnel discipline: operands as jit args, iters
-dependent applications chained in one lax.scan, one scalar fetched.
+Timing follows bench.py: operands as jit args, iters dependent
+applications chained in one lax.scan, one scalar fetched.
 Each measurement prints one JSON line.  Findings live in docs/PERF.md.
 """
 
