@@ -32,6 +32,7 @@ import numpy as np
 
 from ..exceptions import slate_error
 from ..types import Diag, Op, TileKind, Uplo, compose_op, is_complex
+from ..util.trace import span
 from . import layout
 from .grid import Grid
 from .storage import TileStorage
@@ -199,19 +200,22 @@ class BaseMatrix:
         st = self.storage
         if self.is_root_view():
             return st.to_dense()
-        tiles = st.canonical()[self.io:self.io + self._mt,
-                               self.jo:self.jo + self._nt]
-        return layout.untile_dense(tiles, self._m_store(), self._n_store())
+        with span("slate.layout/to_dense"):
+            tiles = st.canonical()[self.io:self.io + self._mt,
+                                   self.jo:self.jo + self._nt]
+            return layout.untile_dense(tiles, self._m_store(),
+                                       self._n_store())
 
     def to_dense(self):
         """Materialise as a plain [m, n] jnp array (op applied, structure
         expanded — symmetric/triangular/band subclasses override _expand)."""
-        d = self._expand(self._dense_store())
-        if self.op is Op.Trans:
-            d = d.T
-        elif self.op is Op.ConjTrans:
-            d = d.conj().T
-        return d
+        with span("slate.layout/to_dense"):
+            d = self._expand(self._dense_store())
+            if self.op is Op.Trans:
+                d = d.T
+            elif self.op is Op.ConjTrans:
+                d = d.conj().T
+            return d
 
     def _expand(self, dense):
         return dense
@@ -246,11 +250,12 @@ class BaseMatrix:
         if self.is_root_view():
             new_st = st.with_dense(dense)
         else:
-            tiles = st.canonical()
-            sub = layout.tile_dense(dense, st.mb, st.nb)
-            tiles = jax.lax.dynamic_update_slice(
-                tiles, sub.astype(tiles.dtype), (self.io, self.jo, 0, 0))
-            new_st = st.with_canonical(tiles)
+            with span("slate.layout/from_dense"):
+                tiles = st.canonical()
+                sub = layout.tile_dense(dense, st.mb, st.nb)
+                tiles = jax.lax.dynamic_update_slice(
+                    tiles, sub.astype(tiles.dtype), (self.io, self.jo, 0, 0))
+                new_st = st.with_canonical(tiles)
         v = self.__class__.__new__(self.__class__)
         BaseMatrix.__init__(v, new_st, self.io, self.jo, self._mt, self._nt,
                             self.op, self.kind)

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..exceptions import slate_error
+from ..util.trace import span
 from . import layout
 from .grid import Grid
 
@@ -92,10 +93,11 @@ class TileStorage:
                 st = cls(jnp.asarray(packed), dense.shape[0],
                          dense.shape[1], mb, nb, grid)
                 return st._shard()
-        dense = jnp.asarray(dense)
-        slate_error(dense.ndim == 2, "from_dense needs a 2D array")
-        tiles = layout.tile_dense(dense, mb, nb)
-        data = layout.canonical_to_cyclic(tiles, grid.p, grid.q)
+        with span("slate.layout/from_dense"):
+            dense = jnp.asarray(dense)
+            slate_error(dense.ndim == 2, "from_dense needs a 2D array")
+            tiles = layout.tile_dense(dense, mb, nb)
+            data = layout.canonical_to_cyclic(tiles, grid.p, grid.q)
         st = cls(data, dense.shape[0], dense.shape[1], mb, nb, grid)
         return st._shard()
 
@@ -105,7 +107,8 @@ class TileStorage:
         Mt, Nt, mb, nb = tiles.shape
         slate_error(Mt == layout.num_tiles(m, mb) and
                     Nt == layout.num_tiles(n, nb), "tile grid mismatch")
-        data = layout.canonical_to_cyclic(tiles, grid.p, grid.q)
+        with span("slate.layout/from_dense"):
+            data = layout.canonical_to_cyclic(tiles, grid.p, grid.q)
         st = cls(data, m, n, mb, nb, grid)
         return st._shard()
 
@@ -140,14 +143,18 @@ class TileStorage:
     # ---- views of the store ----
     def canonical(self):
         """Tiles in natural (i, j) order: [Mt, Nt, mb, nb]."""
-        return layout.cyclic_to_canonical(
-            self.data, self.Mt, self.Nt, self.grid.p, self.grid.q)
+        with span("slate.layout/to_dense"):
+            return layout.cyclic_to_canonical(
+                self.data, self.Mt, self.Nt, self.grid.p, self.grid.q)
 
     def to_dense(self):
-        return layout.untile_dense(self.canonical(), self.m, self.n)
+        with span("slate.layout/to_dense"):
+            return layout.untile_dense(self.canonical(), self.m, self.n)
 
     def with_canonical(self, tiles) -> "TileStorage":
-        data = layout.canonical_to_cyclic(tiles, self.grid.p, self.grid.q)
+        with span("slate.layout/from_dense"):
+            data = layout.canonical_to_cyclic(tiles, self.grid.p,
+                                              self.grid.q)
         st = TileStorage(data, self.m, self.n, self.mb, self.nb, self.grid)
         return st._shard()
 

@@ -35,7 +35,7 @@ from ..types import Diag, Op, Uplo
 from .blas3 import as_root_general, trsm
 from ..internal.potrf import potrf_panel_fused, potrf_panel_ok, potrf_tile
 from ..internal.trsm import tri_inv_lower
-from ..util.trace import annotate
+from ..util.trace import annotate, span
 
 
 def _potrf_dense_blocked(a, nb: int, abft: bool = False):
@@ -69,18 +69,18 @@ def _potrf_dense_blocked(a, nb: int, abft: bool = False):
         # slate-lint: disable=TRC001 -- capability probe: reads only static shape/dtype/plan, never tracer data
         fused = potrf_panel_ok(a.dtype, n - k0, w, nb)
         fac = None
-        upd = a[k0:, k0:k1]
-        if fused:
-            left = (a[k0:, :k0] if k0
-                    else jnp.zeros((n - k0, 0), a.dtype))
-            lead = jnp.conj(a[k0:k1, :k0]).T
-            upd, fac = potrf_panel_fused(a[k0:, k0:k1], left, lead)
-        elif k0:
-            left = a[k0:, :k0]
-            lead = jnp.conj(a[k0:k1, :k0]).T
-            upd = upd - left @ lead
-        if k0:
-            if abft:
+        with span("slate.potrf/herk"):
+            upd = a[k0:, k0:k1]
+            if fused:
+                left = (a[k0:, :k0] if k0
+                        else jnp.zeros((n - k0, 0), a.dtype))
+                lead = jnp.conj(a[k0:k1, :k0]).T
+                upd, fac = potrf_panel_fused(a[k0:, k0:k1], left, lead)
+            elif k0:
+                left = a[k0:, :k0]
+                lead = jnp.conj(a[k0:k1, :k0]).T
+                upd = upd - left @ lead
+            if k0 and abft:
                 exp_r = (jnp.sum(a[k0:, k0:k1], axis=1)
                          - left @ jnp.sum(lead, axis=1))
                 exp_c = (jnp.sum(a[k0:, k0:k1], axis=0)
@@ -88,33 +88,34 @@ def _potrf_dense_blocked(a, nb: int, abft: bool = False):
                 upd, ev = _abft.sum_check(upd, exp_r, exp_c, n_ctx=n,
                                           nb=nb, row0=k0, col0=k0)
                 counts = _abft.add_counts(counts, ev)
-        lkk = faults.maybe_corrupt(
-            "post_panel", fac[:w] if fused else potrf_tile(upd[:w]))
-        if abft:
-            lkk, det, cor = _abft.chol_tile_check(upd[:w], lkk, n_ctx=n)
-            counts = _abft.add_counts(
-                counts, _abft.count_event(det, cor, k0 // nb, k0 // nb))
-        a = a.at[k0:k1, k0:k1].set(lkk)
-        if k1 < n:
-            if fused:
-                panel = fac[w:]          # in-kernel TRSM (A21 U^-1)
-            else:
-                panel = upd[w:] @ jnp.conj(tri_inv_lower(lkk)).T
+        with span("slate.potrf/panel"):
+            lkk = faults.maybe_corrupt(
+                "post_panel", fac[:w] if fused else potrf_tile(upd[:w]))
             if abft:
-                # panel X solves X L^H = R; conjugate-transpose it into
-                # the canonical left product L X^H = R^H and verify via
-                # R's checksums
-                xh, det, cor, _, pj_ = _abft.left_product_check(
-                    lkk, jnp.conj(panel).T,
-                    jnp.conj(jnp.sum(upd[w:], axis=0)),
-                    jnp.conj(jnp.sum(upd[w:], axis=1)),
-                    unit=False, n_ctx=n)
-                panel = jnp.conj(xh).T
+                lkk, det, cor = _abft.chol_tile_check(upd[:w], lkk, n_ctx=n)
                 counts = _abft.add_counts(
-                    counts,
-                    _abft.count_event(det, cor, (k1 + pj_) // nb,
-                                      k0 // nb))
-            a = a.at[k1:, k0:k1].set(panel)
+                    counts, _abft.count_event(det, cor, k0 // nb, k0 // nb))
+            a = a.at[k0:k1, k0:k1].set(lkk)
+            if k1 < n:
+                if fused:
+                    panel = fac[w:]          # in-kernel TRSM (A21 U^-1)
+                else:
+                    panel = upd[w:] @ jnp.conj(tri_inv_lower(lkk)).T
+                if abft:
+                    # panel X solves X L^H = R; conjugate-transpose it
+                    # into the canonical left product L X^H = R^H and
+                    # verify via R's checksums
+                    xh, det, cor, _, pj_ = _abft.left_product_check(
+                        lkk, jnp.conj(panel).T,
+                        jnp.conj(jnp.sum(upd[w:], axis=0)),
+                        jnp.conj(jnp.sum(upd[w:], axis=1)),
+                        unit=False, n_ctx=n)
+                    panel = jnp.conj(xh).T
+                    counts = _abft.add_counts(
+                        counts,
+                        _abft.count_event(det, cor, (k1 + pj_) // nb,
+                                          k0 // nb))
+                a = a.at[k1:, k0:k1].set(panel)
     return a, counts
 
 
@@ -159,7 +160,8 @@ def potrf(A, opts: Options | None = None) -> TriangularMatrix:
         L = TriangularMatrix._from_view(Matrix(st_out), Uplo.Lower)
         # finiteness over the WRITTEN (lower) triangle only — the kernel
         # never touches strictly-upper tiles, which may hold stale input
-        h = _chol_health(jnp.tril(st_out.canonical()), minpiv, minidx)
+        with span("slate.potrf/health"):
+            h = _chol_health(jnp.tril(st_out.canonical()), minpiv, minidx)
         h = _abft_fold(h, _abft.AbftCounts(adet, acor, asite))
         return _finalize_potrf(L, h, uplo, opts)
 
@@ -167,10 +169,11 @@ def potrf(A, opts: Options | None = None) -> TriangularMatrix:
     lfac, counts = _potrf_dense_blocked(full, nb, abft=abft)
     st_out = TileStorage.from_dense(lfac, nb, nb, A.grid)
     L = TriangularMatrix._from_view(Matrix(st_out), Uplo.Lower)
-    d = jnp.abs(jnp.diagonal(lfac))
-    d = jnp.where(jnp.isnan(d), jnp.zeros_like(d), d)
-    minidx = jnp.argmin(d)
-    h = _chol_health(jnp.tril(lfac), d[minidx], minidx)
+    with span("slate.potrf/health"):
+        d = jnp.abs(jnp.diagonal(lfac))
+        d = jnp.where(jnp.isnan(d), jnp.zeros_like(d), d)
+        minidx = jnp.argmin(d)
+        h = _chol_health(jnp.tril(lfac), d[minidx], minidx)
     h = _abft_fold(h, counts)
     return _finalize_potrf(L, h, uplo, opts)
 

@@ -33,7 +33,7 @@ from ..robust import abft as _abft
 from ..robust import faults
 from ..robust import health as _health
 from ..types import Diag, Op, Uplo
-from ..util.trace import annotate
+from ..util.trace import annotate, span
 from .blas3 import as_root_general, trsm
 
 
@@ -102,54 +102,61 @@ def _getrf_dense_blocked(a, nb: int, method: str, tau: float = 1.0,
         k1 = min(k0 + nb, kmax)
         w = k1 - k0
         kt = k0 // nb
-        pan = a[k0:, k0:k1]
-        if method == "nopiv":
-            lu, perm = panel_lu_nopiv(pan)
-        elif method == "tntpiv":
-            bh = pan.shape[0]
-            br = max(nb, (-(-bh // (mpt * nb))) * nb)
-            lu, perm = panel_lu_tournament(pan, block_rows=br, arity=depth)
-        elif tau < 1.0:
-            lu, perm = panel_lu_threshold(pan, tau)
-        else:
-            lu, perm = panel_lu(pan)
-        lu = faults.maybe_corrupt("post_panel", lu)
-        if abft:
-            lu, det, cor, pi, _ = _abft.lu_panel_check(pan, lu, perm,
-                                                       n_ctx=m)
-            counts = _abft.add_counts(counts, _abft.count_event(
-                det, cor, kt + pi // nb, kt))
-        a = a.at[k0:, k0:k1].set(lu)
-        if method != "nopiv":
-            a = a.at[k0:, :k0].set(_apply_row_perm(a[k0:, :k0], perm, 2 * w))
-            a = a.at[k0:, k1:].set(_apply_row_perm(a[k0:, k1:], perm, 2 * w))
-            perm_g = perm_g.at[k0:].set(perm_g[k0:][perm])
-        if k1 < n:
-            l11 = lu[:w, :w]
-            r12 = a[k0:k1, k1:]
-            u12 = tri_inv_lower(l11, unit_diag=True) @ r12
+        with span("slate.getrf/panel"):
+            pan = a[k0:, k0:k1]
+            if method == "nopiv":
+                lu, perm = panel_lu_nopiv(pan)
+            elif method == "tntpiv":
+                bh = pan.shape[0]
+                br = max(nb, (-(-bh // (mpt * nb))) * nb)
+                lu, perm = panel_lu_tournament(pan, block_rows=br,
+                                               arity=depth)
+            elif tau < 1.0:
+                lu, perm = panel_lu_threshold(pan, tau)
+            else:
+                lu, perm = panel_lu(pan)
+            lu = faults.maybe_corrupt("post_panel", lu)
             if abft:
-                u12, det, cor, _, pj = _abft.left_product_check(
-                    l11, u12, jnp.sum(r12, axis=1), jnp.sum(r12, axis=0),
-                    unit=True, n_ctx=m)
+                lu, det, cor, pi, _ = _abft.lu_panel_check(pan, lu, perm,
+                                                           n_ctx=m)
                 counts = _abft.add_counts(counts, _abft.count_event(
-                    det, cor, kt, (k1 + pj) // nb))
-            a = a.at[k0:k1, k1:].set(u12)
-            if k1 < m:
-                l21 = lu[w:, :w]
+                    det, cor, kt + pi // nb, kt))
+            a = a.at[k0:, k0:k1].set(lu)
+        if method != "nopiv":
+            with span("slate.getrf/swap"):
+                a = a.at[k0:, :k0].set(
+                    _apply_row_perm(a[k0:, :k0], perm, 2 * w))
+                a = a.at[k0:, k1:].set(
+                    _apply_row_perm(a[k0:, k1:], perm, 2 * w))
+                perm_g = perm_g.at[k0:].set(perm_g[k0:][perm])
+        if k1 < n:
+            with span("slate.getrf/trsm"):
+                l11 = lu[:w, :w]
+                r12 = a[k0:k1, k1:]
+                u12 = tri_inv_lower(l11, unit_diag=True) @ r12
                 if abft:
-                    tb = a[k1:, k1:]
-                    exp_row = (jnp.sum(tb, axis=1)
-                               - l21 @ jnp.sum(u12, axis=1))
-                    exp_col = (jnp.sum(tb, axis=0)
-                               - jnp.sum(l21, axis=0) @ u12)
-                    tb, ev = _abft.sum_check(tb - l21 @ u12, exp_row,
-                                             exp_col, n_ctx=m, nb=nb,
-                                             row0=k1, col0=k1)
-                    counts = _abft.add_counts(counts, ev)
-                    a = a.at[k1:, k1:].set(tb)
-                else:
-                    a = a.at[k1:, k1:].add(-(l21 @ u12))
+                    u12, det, cor, _, pj = _abft.left_product_check(
+                        l11, u12, jnp.sum(r12, axis=1),
+                        jnp.sum(r12, axis=0), unit=True, n_ctx=m)
+                    counts = _abft.add_counts(counts, _abft.count_event(
+                        det, cor, kt, (k1 + pj) // nb))
+                a = a.at[k0:k1, k1:].set(u12)
+            if k1 < m:
+                with span("slate.getrf/gemm"):
+                    l21 = lu[w:, :w]
+                    if abft:
+                        tb = a[k1:, k1:]
+                        exp_row = (jnp.sum(tb, axis=1)
+                                   - l21 @ jnp.sum(u12, axis=1))
+                        exp_col = (jnp.sum(tb, axis=0)
+                                   - jnp.sum(l21, axis=0) @ u12)
+                        tb, ev = _abft.sum_check(tb - l21 @ u12, exp_row,
+                                                 exp_col, n_ctx=m, nb=nb,
+                                                 row0=k1, col0=k1)
+                        counts = _abft.add_counts(counts, ev)
+                        a = a.at[k1:, k1:].set(tb)
+                    else:
+                        a = a.at[k1:, k1:].add(-(l21 @ u12))
     return a, perm_g, counts
 
 
@@ -307,7 +314,8 @@ def _getrf(A: Matrix, opts: Options | None, method: str):
         An = as_root_general(A, nb, nb, grid=A.grid)
         st = An.storage
         data_in = faults.maybe_corrupt("input", st.data)
-        amax = jnp.max(jnp.abs(data_in))
+        with span("slate.getrf/health"):
+            amax = jnp.max(jnp.abs(data_in))
         la = max(1, int(get_option(opts, Option.Lookahead)))
         data, perm, minpiv, minidx, adet, acor, asite = dist_getrf(
             data_in, st.Nt, A.grid, st.n, method,
@@ -321,20 +329,23 @@ def _getrf(A: Matrix, opts: Options | None, method: str):
             out.dtype)
         out = out.with_canonical(clean)
         F = LUFactors(Matrix(out), perm[: st.m])
-        h = _abft_fold(_lu_health(clean, minpiv, minidx, amax),
-                       _abft.AbftCounts(adet, acor, asite))
+        with span("slate.getrf/health"):
+            h = _abft_fold(_lu_health(clean, minpiv, minidx, amax),
+                           _abft.AbftCounts(adet, acor, asite))
         return _health.finalize(f"getrf[{method}]", F, h, opts,
                                 _singular(f"getrf[{method}]"))
 
     ad = faults.maybe_corrupt("input", A.to_dense())
-    amax = jnp.max(jnp.abs(ad))
+    with span("slate.getrf/health"):
+        amax = jnp.max(jnp.abs(ad))
     lu, perm, counts = _getrf_dense_blocked(ad, nb, method, tau=tau,
                                             mpt=mpt, depth=depth, abft=abft)
     st = TileStorage.from_dense(lu, nb, nb, A.grid)
     F = LUFactors(Matrix(st), perm)
-    udiag = jnp.abs(jnp.diagonal(lu))
-    minidx = jnp.argmin(udiag)
-    h = _abft_fold(_lu_health(lu, udiag[minidx], minidx, amax), counts)
+    with span("slate.getrf/health"):
+        udiag = jnp.abs(jnp.diagonal(lu))
+        minidx = jnp.argmin(udiag)
+        h = _abft_fold(_lu_health(lu, udiag[minidx], minidx, amax), counts)
     return _health.finalize(f"getrf[{method}]", F, h, opts,
                             _singular(f"getrf[{method}]"))
 
@@ -493,8 +504,9 @@ def getrs(F: LUFactors, B, opts: Options | None = None) -> Matrix:
         bp_data = dist_permute_rows(st.data, F.perm, B.grid)
         Bp = Matrix(TileStorage(bp_data, st.m, st.n, st.mb, st.nb, st.grid))
     else:
-        bperm = B.to_dense()[F.perm]
-        Bp = Matrix(TileStorage.from_dense(bperm, B.mb, B.nb, B.grid))
+        with span("slate.getrs/permute"):
+            bperm = B.to_dense()[F.perm]
+            Bp = Matrix(TileStorage.from_dense(bperm, B.mb, B.nb, B.grid))
     Y = trsm("l", 1.0, F.lower(), Bp, opts)
     X = trsm("l", 1.0, F.upper(), Y, opts)
     if faults.active("solve") is not None:

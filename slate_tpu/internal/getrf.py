@@ -42,7 +42,8 @@ def panel_lu(panel):
     below the square part; U upper nb x nb).  XLA's LU up to
     XLA_LU_MAX_ROWS rows, the recursive panel (same pivot rule) above:
     where both compile, XLA's ran 2.5x (128 x 128) to 6.6x (8192 x 512)
-    faster on the chip (tools/panel_lu_time.py, PERF.md).
+    faster on the chip (PERF.md; the benchmark's ``panel_ms.solve``
+    reads the panel's time inside the solve).
     """
     if panel.shape[0] > XLA_LU_MAX_ROWS:
         return panel_lu_recursive(panel)
@@ -62,7 +63,7 @@ def panel_lu_recursive(panel, base: int = 128):
 
     Its solves and updates multiply at HIGHEST: with the TPU's default
     f32 product (one bf16 pass) the factor's ||P A - L U|| / ||A|| was
-    3e-2 to 8e-2 at 512 columns (tools/panel_lu_time.py on the chip),
+    3e-2 to 8e-2 at 512 columns on the chip (PERF.md),
     since partial pivoting's unit-lower L11 can have a large inverse."""
     w = panel.shape[1]
     if w <= base:
