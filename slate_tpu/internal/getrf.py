@@ -40,10 +40,11 @@ def panel_lu(panel):
 
     Returns (lu, perm) with panel[perm] = L @ U (L unit lower incl. rows
     below the square part; U upper nb x nb).  XLA's LU up to
-    XLA_LU_MAX_ROWS rows, the recursive panel (same pivot rule) above:
-    where both compile, XLA's ran 2.5x (128 x 128) to 6.6x (8192 x 512)
-    faster on the chip (PERF.md; the benchmark's ``panel_ms.solve``
-    reads the panel's time inside the solve).
+    XLA_LU_MAX_ROWS rows, the recursive panel (same pivot rule) above.
+    Where both compile, XLA's ran 2.5x (128 x 128) to 6.6x (8192 x 512)
+    faster on the chip, but against a recursive panel that still
+    gathered every row at each split: a stale ratio, not re-measured.
+    ``benchmark/phase_table.py`` reads the panel's time inside a solve.
     """
     if panel.shape[0] > XLA_LU_MAX_ROWS:
         return panel_lu_recursive(panel)
@@ -61,24 +62,49 @@ def panel_lu_recursive(panel, base: int = 128):
     A 128-wide leaf moves the same lane-padded bytes per step as a
     narrower one and compiles ~5x faster at 16384 x 512.
 
+    Each split's permutation is a product of h transpositions, so it
+    displaces at most 2h rows: the top h and the h pivot rows.  Only
+    those rows are gathered and written back (:func:`_permute_rows`);
+    a whole ``panel[p1]`` gather is a loop over all W rows on the chip.
+
     Its solves and updates multiply at HIGHEST: with the TPU's default
     f32 product (one bf16 pass) the factor's ||P A - L U|| / ||A|| was
     3e-2 to 8e-2 at 512 columns on the chip (PERF.md),
     since partial pivoting's unit-lower L11 can have a large inverse."""
+    lu, perm, _ = _panel_lu_recursive(panel, base)
+    return lu, perm
+
+
+def _panel_lu_recursive(panel, base: int):
+    """:func:`panel_lu_recursive` that also returns its pivot sequence
+    (LAPACK's ipiv, 0-based: step j swapped rows j and ipiv[j])."""
     w = panel.shape[1]
     if w <= base:
-        return panel_lu_threshold(panel, 1.0)
+        return _panel_lu_pivots(panel, 1.0)
     h = w // 2
     with jax.default_matmul_precision("highest"):
-        lu1, p1 = panel_lu_recursive(panel[:, :h], base)
-        right = panel[p1, h:]
+        lu1, p1, piv1 = _panel_lu_recursive(panel[:, :h], base)
+        right = _permute_rows(panel[:, h:], p1, piv1)
         l11 = jnp.tril(lu1[:h], -1) + jnp.eye(h, dtype=panel.dtype)
         u12 = tri_inv_lower(l11, unit_diag=True) @ right[:h]
-        lu2, p2 = panel_lu_recursive(right[h:] - lu1[h:] @ u12, base)
+        lu2, p2, piv2 = _panel_lu_recursive(right[h:] - lu1[h:] @ u12,
+                                            base)
     top = jnp.concatenate([lu1[:h], u12], axis=1)
-    bot = jnp.concatenate([lu1[h:][p2], lu2], axis=1)
-    perm = jnp.concatenate([p1[:h], p1[h:][p2]])
-    return jnp.concatenate([top, bot], axis=0), perm
+    bot = jnp.concatenate([_permute_rows(lu1[h:], p2, piv2), lu2], axis=1)
+    perm = jnp.concatenate([p1[:h], _permute_rows(p1[h:], p2, piv2)])
+    return (jnp.concatenate([top, bot], axis=0), perm,
+            jnp.concatenate([piv1, piv2 + h]))
+
+
+def _permute_rows(mat, perm, piv):
+    """``mat[perm]`` for a permutation made by the k row swaps ``piv``:
+    only rows ``arange(k)`` and ``piv`` can move, so only that bundle is
+    gathered and scattered.  An index may repeat (a pivot in the top
+    block, or the same row chosen twice); each copy writes the same
+    value."""
+    k = piv.shape[0]
+    idx = jnp.concatenate([jnp.arange(k, dtype=piv.dtype), piv])
+    return mat.at[idx].set(mat[perm[idx]])
 
 
 # ---- out-of-core step kernels (drivers/lu.py getrf_ooc) ----
@@ -193,12 +219,19 @@ def panel_lu_threshold(panel, tau):
     One fori_loop of masked rank-1 steps; returns (lu, perm) like
     :func:`panel_lu`.
     """
+    lu, perm, _ = _panel_lu_pivots(panel, tau)
+    return lu, perm
+
+
+def _panel_lu_pivots(panel, tau):
+    """:func:`panel_lu_threshold` that also returns its pivot sequence
+    (LAPACK's ipiv, 0-based: step j swapped rows j and ipiv[j])."""
     W, nb = panel.shape
     rows = jnp.arange(W)
     tau = jnp.asarray(tau, jnp.real(panel).dtype)
 
     def body(j, carry):
-        a, perm = carry
+        a, perm, ipiv = carry
         col = lax.dynamic_index_in_dim(a, j, axis=1, keepdims=False)
         mag = jnp.where(rows >= j, jnp.abs(col), -jnp.ones_like(
             jnp.abs(col)))
@@ -220,11 +253,11 @@ def panel_lu_threshold(panel, tau):
         rowj = jnp.where(cols > j, a[j], jnp.zeros_like(a[j]))
         a = a - jnp.outer(l, rowj)
         a = a.at[:, j].set(jnp.where(rows > j, l, colj))
-        return a, perm
+        return a, perm, ipiv.at[j].set(pos)
 
-    lu, perm = lax.fori_loop(0, min(W, nb), body,
-                             (panel, jnp.arange(W)))
-    return lu, perm
+    steps = min(W, nb)
+    return lax.fori_loop(0, steps, body,
+                         (panel, rows, jnp.zeros(steps, rows.dtype)))
 
 
 def _lu_select_ok(blocks, nb: int) -> bool:

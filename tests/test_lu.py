@@ -182,3 +182,88 @@ def test_recursive_panel_matches_partial_pivoting(rng, W, w, dtype):
     if not np.iscomplexobj(a):
         _, _, ref = lax.linalg.lu(jnp.asarray(a))
         assert (perm == np.asarray(ref)).all()
+
+
+def _gathering_panel_lu_recursive(panel, base: int = 128):
+    """The recursive panel as it was when each split gathered every row
+    (``panel[p1, h:]``): the oracle the row-bundle version must match
+    bit for bit."""
+    from slate_tpu.internal.getrf import panel_lu_threshold
+    from slate_tpu.internal.trsm import tri_inv_lower
+    w = panel.shape[1]
+    if w <= base:
+        return panel_lu_threshold(panel, 1.0)
+    h = w // 2
+    with jax.default_matmul_precision("highest"):
+        lu1, p1 = _gathering_panel_lu_recursive(panel[:, :h], base)
+        right = panel[p1, h:]
+        l11 = jnp.tril(lu1[:h], -1) + jnp.eye(h, dtype=panel.dtype)
+        u12 = tri_inv_lower(l11, unit_diag=True) @ right[:h]
+        lu2, p2 = _gathering_panel_lu_recursive(right[h:] - lu1[h:] @ u12,
+                                                base)
+    top = jnp.concatenate([lu1[:h], u12], axis=1)
+    bot = jnp.concatenate([lu1[h:][p2], lu2], axis=1)
+    perm = jnp.concatenate([p1[:h], p1[h:][p2]])
+    return jnp.concatenate([top, bot], axis=0), perm
+
+
+@pytest.mark.parametrize("kind", ["random", "no_swaps", "swap_every_step",
+                                  "pivot_in_top_block"])
+@pytest.mark.parametrize("W,w,base", [(300, 128, 16), (257, 40, 16),
+                                      (1000, 64, 16), (2048, 256, 64)])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_recursive_panel_bit_identical_to_gathering(rng, dtype, W, w, base,
+                                                    kind):
+    """Moving only the displaced rows at each split gives the same factor
+    and permutation, bit for bit, as gathering every row, whether no row
+    moves, one moves at every step, or a pivot lands inside the top
+    block (repeated indices in the bundle)."""
+    import functools
+
+    from slate_tpu.internal.getrf import panel_lu_recursive
+    a = rng.standard_normal((W, w))
+    if kind == "no_swaps":        # column-diagonally dominant
+        a[:w] += 2 * W * np.eye(w)
+    elif kind == "swap_every_step":   # rows in ascending magnitude
+        a *= (1.0 + np.arange(W)[:, None]) ** 2
+    elif kind == "pivot_in_top_block":
+        a[:w] *= 10.0
+    a = jnp.asarray(a.astype(dtype))
+    lu, perm = jax.jit(functools.partial(panel_lu_recursive, base=base))(a)
+    ref_lu, ref_perm = jax.jit(functools.partial(
+        _gathering_panel_lu_recursive, base=base))(a)
+    assert np.array_equal(np.asarray(lu), np.asarray(ref_lu))
+    assert np.array_equal(np.asarray(perm), np.asarray(ref_perm))
+    # the row swaps that built perm (LAPACK's ipiv): the case is the one
+    # its name says
+    cur, piv = np.arange(W), np.zeros(w, int)
+    for j, row in enumerate(np.asarray(perm)[:w]):
+        piv[j] = np.flatnonzero(cur == row)[0]
+        cur[[j, piv[j]]] = cur[[piv[j], j]]
+    steps = np.arange(w)
+    if kind == "no_swaps":
+        assert (piv == steps).all()
+    elif kind == "swap_every_step":
+        assert (piv > steps).all()
+    elif kind == "pivot_in_top_block":
+        assert ((piv > steps) & (piv < w)).any()
+
+
+def test_recursive_panel_moves_only_the_pivot_rows():
+    """At 16384 x 512 every gather and scatter of the recursive panel
+    moves at most 512 rows (2 x the 256-wide split): the whole-panel
+    gathers were 16384 rows, each a per-row loop on the chip."""
+    import re
+
+    from slate_tpu.internal.getrf import panel_lu_recursive
+    txt = jax.jit(panel_lu_recursive).lower(
+        jax.ShapeDtypeStruct((16384, 512), jnp.float32)).as_text()
+    # the indices operand's type follows the attributes (gather) or the
+    # update region (scatter)
+    found = re.findall(
+        r'"stablehlo\.(gather|scatter)"\(.*?(?:\}>|\}\)) : '
+        r'\(tensor<[^>]*>, tensor<([0-9x]*)[a-z]', txt, flags=re.S)
+    kinds = {k for k, _ in found}
+    assert kinds == {"gather", "scatter"}
+    rows = [int(d.split("x")[0]) if d else 1 for _, d in found]
+    assert max(rows) <= 512, sorted(rows)[-5:]
