@@ -52,6 +52,7 @@ from ..options import (ErrorPolicy, MethodEig, MethodGels, MethodLU,
                        MethodSvd, Option, Options, get_option, resolve_abft,
                        resolve_speculate, select_gels_method,
                        select_lu_method)
+from ..util.trace import span
 from . import health as _h
 from .precision import resolve_precision
 
@@ -213,7 +214,10 @@ def _chol_attempt(A, B, opts):
     o = _with(opts, ErrorPolicy=ErrorPolicy.Info)
     L, fh = _chol.potrf(A, o)
     X = _chol.potrs(L, B, o)
-    return (L, X), _h.merge(fh, _h.from_result(X.storage.data))
+    # on a mesh, X's finiteness is a reduction across chips
+    with span("slate.potrs/health"):
+        xh = _h.from_result(X.storage.data)
+    return (L, X), _h.merge(fh, xh)
 
 
 def _round_bf16(M):
