@@ -49,22 +49,6 @@ class LUFactors(NamedTuple):
         return TriangularMatrix._from_view(self.LU, Uplo.Upper)
 
 
-def _apply_row_perm(mat, perm, bound: int):
-    """Apply a row permutation that displaces at most ``bound`` rows by
-    touching ONLY those rows (gather + scatter of the bundle) — a full
-    ``mat[perm]`` gather reads and rewrites the entire trailing matrix
-    per panel.  Partial pivoting, threshold pivoting and the tournament
-    placement are all products of <= nb transpositions, so bound = 2 nb.
-    """
-    W = perm.shape[0]
-    if W == 0 or mat.shape[1] == 0:
-        return mat
-    k = min(W, bound)
-    moved = (perm != jnp.arange(W)).astype(jnp.int32)
-    _, idx = lax.top_k(moved, k)
-    return mat.at[idx].set(mat[perm[idx]], unique_indices=True)
-
-
 def _getrf_dense_blocked(a, nb: int, method: str, tau: float = 1.0,
                          mpt: int = 4, depth: int = 2, abft: bool = False):
     """Blocked right-looking LU, statically-shaped panels (unrolled).
@@ -73,9 +57,19 @@ def _getrf_dense_blocked(a, nb: int, method: str, tau: float = 1.0,
     reference's lapack panel kernel); the nopiv and tournament panels
     route through internal/getrf.py's tuned seams, which dispatch to the
     fused Pallas panel kernels (internal/pallas_lu.py) when the plan
-    cache selects them (slate_tpu.tune, docs/TUNING.md).  The trailing
-    row exchange touches
-    only the <= 2 nb displaced rows, and the U12 solve is one MXU gemm
+    cache selects them (slate_tpu.tune, docs/TUNING.md).  Each step's
+    row exchange moves whole rows of ``a`` in place, across every
+    column, with no slice of a block left or right of the panel to
+    write back.  Partial pivoting, threshold pivoting and the
+    tournament placement are all products of the w transpositions
+    (j, p_j), j < w, p_j >= j, so a row that lands below the top w of
+    the panel's rows came from the top w: the w new top rows are
+    gathered, the w old ones scattered to where ``perm`` sends them,
+    and the new top block written as one contiguous block.  The move
+    comes before the factored panel is written: it permutes the
+    panel's columns with the rest of each row, and the write then
+    overwrites them with ``lu``, already in pivot order (writing ``lu``
+    first would permute it twice).  The U12 solve is one MXU gemm
     against the inverted unit-L11 (internal/trsm.py tri_inv_lower) —
     ref: getrf.cc:174-215 trailing task.  ``tau`` < 1 switches to
     threshold pivoting (Option.PivotThreshold); ``mpt``
@@ -121,14 +115,17 @@ def _getrf_dense_blocked(a, nb: int, method: str, tau: float = 1.0,
                                                            n_ctx=m)
                 counts = _abft.add_counts(counts, _abft.count_event(
                     det, cor, kt + pi // nb, kt))
-            a = a.at[k0:, k0:k1].set(lu)
         if method != "nopiv":
             with span("slate.getrf/swap"):
-                a = a.at[k0:, :k0].set(
-                    _apply_row_perm(a[k0:, :k0], perm, 2 * w))
-                a = a.at[k0:, k1:].set(
-                    _apply_row_perm(a[k0:, k1:], perm, 2 * w))
+                # old top rows that stay in the top block are written
+                # twice; the contiguous write of the new top block wins
+                dest = jnp.argsort(perm)[:w]
+                top = a[k0 + perm[:w]]
+                a = a.at[k0 + dest].set(a[k0:k1], unique_indices=True)
+                a = lax.dynamic_update_slice_in_dim(a, top, k0, 0)
                 perm_g = perm_g.at[k0:].set(perm_g[k0:][perm])
+        with span("slate.getrf/panel"):
+            a = a.at[k0:, k0:k1].set(lu)
         if k1 < n:
             with span("slate.getrf/trsm"):
                 l11 = lu[:w, :w]

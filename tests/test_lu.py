@@ -267,3 +267,117 @@ def test_recursive_panel_moves_only_the_pivot_rows():
     assert kinds == {"gather", "scatter"}
     rows = [int(d.split("x")[0]) if d else 1 for _, d in found]
     assert max(rows) <= 512, sorted(rows)[-5:]
+
+
+def test_row_exchange_moves_only_the_displaced_rows():
+    """At 2048 x 2048, nb=256, every gather and scatter of matrix rows
+    under the swap moves at most 256 rows (nb: the new top rows in, the
+    old ones out), and both act on the whole [m, n] matrix, not on a
+    slice of the blocks left or right of the panel that is then written
+    back.  (``perm_g``, the global permutation vector, is updated
+    whole.)"""
+    import functools
+    import re
+
+    from slate_tpu.drivers.lu import _getrf_dense_blocked
+    m = n = 2048
+    txt = jax.jit(functools.partial(
+        _getrf_dense_blocked, nb=256, method="partial")).lower(
+        jax.ShapeDtypeStruct((m, n), jnp.float32)).as_text(debug_info=True)
+    where = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', txt, flags=re.M))
+    found = re.findall(
+        r'"stablehlo\.(gather|scatter)"\(.*?(?:\}>|\}\)) : '
+        r'\(tensor<((?:\d+x)*)[a-z][^>]*>, tensor<(\d+)x[^)]*\)'
+        r'[^\n]*? loc\((#loc\d+)\)', txt, flags=re.S)
+    # rank-2 operands: rows of the matrix, not entries of perm_g
+    moves = [(kind, operand, int(rows)) for kind, operand, rows, loc in found
+             if "slate.getrf/swap/" in where[loc]
+             and operand.count("x") == 2]
+    assert [k for k, _, _ in moves] == ["gather", "scatter"] * (n // 256)
+    for kind, operand, rows in moves:
+        assert operand == f"{m}x{n}x", (kind, operand)
+        assert rows <= 256, (kind, rows)
+
+
+def _two_block_getrf_dense_blocked(a, nb, method, tau=1.0, mpt=4, depth=2):
+    """The one-chip blocked LU as it was when each step sliced the blocks
+    left and right of the panel, moved the displaced rows inside each
+    copy and wrote both blocks back: the oracle the whole-row move must
+    match bit for bit."""
+    from jax import lax
+
+    from slate_tpu.internal.getrf import (panel_lu, panel_lu_threshold,
+                                          panel_lu_tournament)
+    from slate_tpu.internal.trsm import tri_inv_lower
+
+    def apply_row_perm(mat, perm, bound):
+        W = perm.shape[0]
+        if W == 0 or mat.shape[1] == 0:
+            return mat
+        moved = (perm != jnp.arange(W)).astype(jnp.int32)
+        _, idx = lax.top_k(moved, min(W, bound))
+        return mat.at[idx].set(mat[perm[idx]], unique_indices=True)
+
+    m, n = a.shape
+    perm_g = jnp.arange(m)
+    for k0 in range(0, min(m, n), nb):
+        k1 = min(k0 + nb, m, n)
+        w = k1 - k0
+        pan = a[k0:, k0:k1]
+        if method == "tntpiv":
+            bh = pan.shape[0]
+            br = max(nb, (-(-bh // (mpt * nb))) * nb)
+            lu, perm = panel_lu_tournament(pan, block_rows=br, arity=depth)
+        elif tau < 1.0:
+            lu, perm = panel_lu_threshold(pan, tau)
+        else:
+            lu, perm = panel_lu(pan)
+        a = a.at[k0:, k0:k1].set(lu)
+        a = a.at[k0:, :k0].set(apply_row_perm(a[k0:, :k0], perm, 2 * w))
+        a = a.at[k0:, k1:].set(apply_row_perm(a[k0:, k1:], perm, 2 * w))
+        perm_g = perm_g.at[k0:].set(perm_g[k0:][perm])
+        if k1 < n:
+            u12 = tri_inv_lower(lu[:w, :w], unit_diag=True) @ a[k0:k1, k1:]
+            a = a.at[k0:k1, k1:].set(u12)
+            if k1 < m:
+                a = a.at[k1:, k1:].add(-(lu[w:, :w] @ u12))
+    return a, perm_g
+
+
+@pytest.mark.parametrize("rows", ["random", "ascending"])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("m,n,nb", [(96, 96, 32), (100, 100, 32),
+                                    (150, 70, 32), (70, 150, 32)])
+@pytest.mark.parametrize("method,tau", [("partial", 1.0),
+                                        ("partial", 0.5),
+                                        ("tntpiv", 1.0)])
+def test_row_exchange_bit_identical_to_two_block(rng, method, tau, m, n, nb,
+                                                 dtype, rows):
+    """Moving the displaced rows of the whole matrix in place, then
+    writing the panel, gives the same factor and permutation, bit for
+    bit, as moving them inside slices of the left and right blocks and
+    writing both back: square, ragged last panel, tall and wide, with
+    rows in ascending magnitude so that every step swaps."""
+    import functools
+
+    from slate_tpu.drivers.lu import _getrf_dense_blocked
+    a = rng.standard_normal((m, n))
+    if rows == "ascending":
+        a *= (1.0 + np.arange(m)[:, None]) ** 2
+    a = jnp.asarray(a.astype(dtype))
+    lu, perm, _ = jax.jit(functools.partial(
+        _getrf_dense_blocked, nb=nb, method=method, tau=tau))(a)
+    ref_lu, ref_perm = jax.jit(functools.partial(
+        _two_block_getrf_dense_blocked, nb=nb, method=method, tau=tau))(a)
+    assert np.array_equal(np.asarray(lu), np.asarray(ref_lu))
+    assert np.array_equal(np.asarray(perm), np.asarray(ref_perm))
+    if rows == "ascending":
+        # the row swaps that built perm (LAPACK's ipiv): every panel
+        # step swaps
+        k = min(m, n)
+        cur, piv = np.arange(m), np.zeros(k, int)
+        for j, row in enumerate(np.asarray(perm)[:k]):
+            piv[j] = np.flatnonzero(cur == row)[0]
+            cur[[j, piv[j]]] = cur[[piv[j], j]]
+        swapped = piv > np.arange(k)
+        assert all(swapped[k0:k0 + nb].any() for k0 in range(0, k, nb))
