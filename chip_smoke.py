@@ -2,17 +2,16 @@
 """Chip smoke: drive slate_tpu's public solver path once on a TPU.
 
 ``python chip_smoke.py`` runs, in ONE process on one chip, the f32 phases
-below at bench.py's FULL sizes (BASELINE configs 1-5 cut to what one
-16 GB v5e chip holds) and holds every answer to the library's own
-acceptance test (robust/certify.py):
+below at BASELINE configs 1-5 cut to what one 16 GB v5e chip holds, and
+holds every answer to the library's own acceptance test
+(robust/certify.py):
 
   gemm    n=16384, nb=1024: three 1 GiB operands
   posv    n=16384, nb=512, nrhs=256
   gesv    n=16384, nb=512, nrhs=256, partial pivoting
   gels    131072 x 1024, nb=256, nrhs=64
-  heev    n=2048, values only — NOT bench.py's 4096, an open bring-up
-          failure: XLA's eigh compile at 4096 filled the chip host's
-          40 GiB (PERF.md "Findings" and "Open questions", PR 21)
+  heev    n=2048, values only — not 4096, an open bring-up failure:
+          XLA's eigh compile at 4096 filled the chip host's 40 GiB
   serve   96 mixed solve / chol_solve / least_squares_solve problems,
           sizes 48-320, through serve.Server: cold, then warm; every
           answer certified on the host
@@ -413,8 +412,8 @@ def heev_job(st, jax, grid, n, nb):
 
 def serve_requests(rng, problems, nrhs, sizes, square_ls=False,
                    well_conditioned=False):
-    """Seeded mixed workload: three ops round-robin over ``sizes``
-    (bench.py bench_serve_mixed's construction).  ``well_conditioned``
+    """Seeded mixed workload: three ops round-robin over ``sizes``;
+    solve matrices get 4 I on the diagonal.  ``well_conditioned``
     makes every solve and least-squares matrix G / sqrt(n) + 3 I, whose
     spectrum lies in the unit disc around 3, so kappa(A) stays near 2 —
     as chol_solve's A A^T / n + I (kappa <= 5) already does."""

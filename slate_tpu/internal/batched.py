@@ -5,7 +5,7 @@ bucket stack (serve/server.py pad_square/pad_tall): problem i of size
 s_i occupies the top-left s_i x s_i of its [n, n] slot, the rest of the
 diagonal is I, and filler slots are whole identity (or zero for QR row
 padding).  The vmapped XLA cores then factor every slot at the FULL
-bucket size — `bench_serve_mixed` records the padding-waste% that burns.
+bucket size — ``serve_batch`` events record the padding waste that burns.
 
 These drivers are the ragged alternative: a left-looking blocked loop
 over the bucket's block columns where every panel step is ONE batched

@@ -282,7 +282,7 @@ def panel_lu_tournament(panel, block_rows: int, arity: int = 2):
     rounds: merge ``arity`` candidate sets at a time (Option.Depth — the
     fan-in), again one batched LU per LEVEL — the tree is latency-bound,
     and XLA's batched LU amortizes its per-column While latency across
-    the whole batch (measured 5.4x, docs/ceiling.jsonl xla_lu batch32).
+    the whole batch (5.4x at batch 32, a pre-benchmark measurement).
     Finally the chosen rows move to the top via a VECTORIZED permutation
     that displaces at most 2 nb rows (the bound the distributed bundle
     exchange relies on), and the permuted panel is factored with no

@@ -2,8 +2,8 @@
 
 The reference factors diagonal tiles with vendor LAPACK potrf
 (ref: src/internal/internal_potrf.cc:132).  XLA's TPU Cholesky runs a
-per-column While loop — measured 2.07 ms for a 512x512 f32 tile
-(docs/ceiling.jsonl xla_cholesky_512), which times 32 sequential panel
+per-column While loop — 2.07 ms for a 512x512 f32 tile (a
+pre-benchmark v5e measurement), which times 32 sequential panel
 steps is the single largest cost in a 16k potrf.  This kernel keeps the
 whole tile in VMEM for the entire factorization.
 

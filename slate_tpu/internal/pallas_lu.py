@@ -3,8 +3,8 @@
 The CALU tournament (internal/getrf.py panel_lu_tournament) needs each
 row block's nb partial-pivot rows (ref: internal_getrf_tntpiv.cc round-1
 LUs).  XLA's pivoted LU streams the whole [W, nb] chunk from HBM once
-per column — measured 31 us/column at [4096, 512] (docs/ceiling.jsonl
-xla_lu_4096x512), i.e. 15.8 ms for work whose flops cost ~0.1 ms.  This
+per column — 31 us/column at [4096, 512] (a pre-benchmark v5e
+measurement), i.e. 15.8 ms for work whose flops cost ~0.1 ms.  This
 kernel keeps the chunk in VMEM TRANSPOSED ([nb, W]: columns of A on
 sublanes, rows of A on lanes) so each elimination step touches one 8-row
 slab; pivoted rows are MASKED out of the search instead of physically

@@ -3,7 +3,8 @@
 Analog of the reference's internal_potrf.cc:132 (lapack::potrf on the
 diagonal tile, host or device).  The reference delegates the tile factor
 to vendor LAPACK; on TPU the vendor seam (XLA's Cholesky) runs a
-per-column While loop — 2.07 ms per 512 f32 tile (docs/ceiling.jsonl).
+per-column While loop — 2.07 ms per 512 f32 tile (a pre-benchmark v5e
+measurement).
 
 Kernel choice is now a TUNED decision: both the single-tile factor
 (potrf_tile) and the fused panel step (potrf_panel_fused: rank-k update

@@ -1,12 +1,12 @@
 """internal::rbt — recursive random butterfly transforms (RBT / PRBT).
 
 Partial pivoting is a sequential, latency-bound row hunt per panel column;
-on TPU it is the one part of LU that cannot feed the MXU (docs/PERF.md:
-the CALU tournament's ~400 ms pivoting wall at n=16384 vs posv's 66 ms
-pivot-free Cholesky floor).  The classical alternative (Parker '95;
-Baboulin et al., "Accelerating linear system solutions using randomization
-techniques") is to precondition with random butterflies so that NO pivoting
-is needed with probability ~1: factor
+on TPU it is the one part of LU that cannot feed the MXU (a
+pre-benchmark measurement: the CALU tournament's ~400 ms pivoting wall
+at n=16384 vs posv's 66 ms pivot-free Cholesky floor).  The classical
+alternative (Parker '95; Baboulin et al., "Accelerating linear system
+solutions using randomization techniques") is to precondition with random
+butterflies so that NO pivoting is needed with probability ~1: factor
 
     A~ = U^T diag(A, I_pad) V,      x = V y,   A~ y = U^T [b; 0]
 

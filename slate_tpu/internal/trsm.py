@@ -38,8 +38,8 @@ def tri_inv_lower(L, unit_diag: bool = False, base: int = 32):
 
     The reference's trsm panels run forward substitution (one column of
     latency per step); on TPU a [15872, 512] triangular_solve measured
-    675 GFLOP/s (docs/ceiling.jsonl) because the per-column While loop
-    cannot feed the MXU.  The MAGMA-style alternative is to invert the
+    675 GFLOP/s (a pre-benchmark measurement) because the per-column
+    While loop cannot feed the MXU.  The MAGMA-style alternative is to invert the
     nb x nb diagonal block once and turn every panel solve into one MXU
     gemm.  This inversion is itself log-depth and fully batched:
 
@@ -145,7 +145,7 @@ def trsm_left_blocked(ad, bd, *, lower: bool, trans: bool, conj: bool,
     (exact; see _pad_tri).
 
     XLA's monolithic triangular_solve runs a per-column While loop
-    (measured 4.1 TFLOP/s on [16384, 256], docs/ceiling.jsonl); this is
+    (4.1 TFLOP/s on [16384, 256], a pre-benchmark measurement); this is
     the reference's work_trsm block sweep (ref: work/work_trsm.cc)
     reshaped so every op is a matmul."""
     ad, n0 = _pad_tri(ad, nb)

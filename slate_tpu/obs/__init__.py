@@ -1,6 +1,6 @@
 """slate_tpu.obs — the observability spine (docs/OBSERVABILITY.md).
 
-Four pieces, all host-side and zero-overhead when disabled:
+Six pieces, all host-side and zero-overhead when disabled:
 
 - structured driver events (:mod:`events`): one JSON record per public
   driver call — op, shapes, resolved policy/speculate/abft, path taken,
@@ -13,18 +13,16 @@ Four pieces, all host-side and zero-overhead when disabled:
   ``python -m slate_tpu.obs`` CLI;
 - device-time truth (:mod:`flops`): one analytic flop/byte model per
   public op feeding ``device_ms``/``mfu``/``achieved_gbps`` on events
-  under the opt-in :func:`timing` mode — bench.py prices its lines
-  from the SAME registry;
-- serving SLOs (:mod:`slo`) over the flight-recorder fields, and a
-  bench-round regression sentinel (:mod:`compare`) behind
-  ``--slo`` / ``--compare``.
+  under the opt-in :func:`timing` mode;
+- serving SLOs (:mod:`slo`) over the flight-recorder fields, behind
+  ``--slo``.
 
 The jaxpr-identity guarantee: enabling any of this changes NOTHING in
 traced computations (no io_callback, no extra ops) — recording reads
 returned HealthInfo and host clocks only.
 """
 
-from . import compare, flops, slo
+from . import flops, slo
 from .events import (SCHEMA, boundary_enter, boundary_exit, clear,
                      configure, disable, enable, enabled, emit_serve_batch,
                      emit_serve_quarantine, emit_serve_shed, note_health,
@@ -38,7 +36,7 @@ from .tracer import SpanRecorder, record_spans
 
 __all__ = [
     "SCHEMA", "SlateRetraceWarning", "SpanRecorder", "boundary_enter",
-    "boundary_exit", "clear", "compare", "configure", "disable", "enable",
+    "boundary_exit", "clear", "configure", "disable", "enable",
     "enabled", "emit_serve_batch", "emit_serve_quarantine",
     "emit_serve_shed", "flops", "note_health", "note_path",
     "note_plan", "note_resolved", "recent", "record_spans", "recording",

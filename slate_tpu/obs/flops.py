@@ -1,10 +1,10 @@
 """Analytic flop/byte models per public op — the MFU denominator.
 
-Every MFU number in the repo comes from here: ``bench.py`` and the
-structured-event layer (events.py) both resolve the SAME registered
-model for an op, so a bench line and a production event for the same
-shapes can never disagree about what "the flops" are (asserted in
-tests/test_obs_perf.py).  Formulas follow the reference tester's
+The structured-event layer (events.py) resolves an op's registered
+model here for the ``mfu`` of a timed driver event (asserted in
+tests/test_obs_perf.py).  The benchmark keeps its own copy of the
+counts (benchmark/flops.py), so that no change to the library moves
+its yardstick.  Formulas follow the reference tester's
 nominal counts (gemm 2mnk ref src/gemm.cc:24, potrf n^3/3 ref
 src/potrf.cc:334, getrf 2n^3/3, geqrf 2mn^2 - 2n^3/3 — testsweeper
 gflop helpers); methods that do different work (gels via CholQR, svd

@@ -1,17 +1,12 @@
-"""Metrics aggregation: event/bench JSONL -> per-op tables.
+"""Metrics aggregation: event JSONL -> per-op tables.
 
-Consumes the two line formats the repo emits —
-
-- ``slate-obs-v1`` driver events (obs/events.py), spans, and
-  ``serve_batch`` records (serve/server.py),
-- ``slate-bench-v1`` bench lines (bench.py; pre-schema BENCH_r*.json
-  lines are accepted too: anything with a ``metric`` key),
-
-and aggregates them into per-op latency percentiles (p50/p99 of
+Consumes ``slate-obs-v1`` driver events (obs/events.py), spans,
+``serve_batch`` records (serve/server.py) and checkpoint records, and
+aggregates them into per-op latency percentiles (p50/p99 of
 ``dur_ms``), escalation / ABFT / certificate-failure rates, plan-usage
 tables, a serving table (bucket occupancy p50/p99, padding waste,
-escalations per 1k problems, retrace/compile counts) and a bench-round
-summary.  Pure stdlib; the CLI front-end is obs/__main__.py.
+escalations per 1k problems, retrace/compile counts) and a durability
+table.  Pure stdlib; the CLI front-end is obs/__main__.py.
 """
 
 from __future__ import annotations
@@ -20,7 +15,6 @@ import json
 import math
 
 EVENT_SCHEMA = "slate-obs-v1"
-BENCH_SCHEMA = "slate-bench-v1"
 
 
 def load_lines(paths) -> list[dict]:
@@ -32,13 +26,8 @@ def load_lines(paths) -> list[dict]:
 def load_records(paths) -> tuple[list[dict], int]:
     """Like :func:`load_lines` but also counts MALFORMED lines — lines
     that look like truncated/garbled JSON records (start with ``{`` but
-    fail to parse, exactly what a watchdog-killed run leaves behind).
-    Ordinary interleaved log lines stay silently skipped.
-
-    Also accepts the historical ``BENCH_r*.json`` wrapper format: a
-    single pretty-printed JSON object whose ``tail`` string holds the
-    run's log+JSONL mixed output — the metric lines inside ``tail`` are
-    extracted as records."""
+    fail to parse, exactly what a killed run leaves behind).  Ordinary
+    interleaved log lines stay silently skipped."""
     out: list[dict] = []
     malformed = 0
     for path in paths:
@@ -59,25 +48,18 @@ def load_records(paths) -> tuple[list[dict], int]:
                     malformed += 1
             continue
         if isinstance(whole, dict):
-            if isinstance(whole.get("tail"), str):
-                # pre-schema bench-round wrapper: harvest the tail
-                n, m = _parse_lines(whole["tail"], out)
-                malformed += m
-                if n == 0 and m == 0:
-                    out.append(whole)      # no records inside: keep wrapper
-            else:
-                out.append(whole)          # single-record file
+            out.append(whole)              # single-record file
             continue
-        malformed += _parse_lines(text, out)[1]
+        malformed += _parse_lines(text, out)
     return out, malformed
 
 
-def _parse_lines(text: str, out: list) -> tuple[int, int]:
+def _parse_lines(text: str, out: list) -> int:
     """Append each parseable JSON-dict line of ``text`` to ``out``;
-    returns (records appended, malformed lines).  A line counts as
-    malformed only when it *starts* like a JSON record (``{``) and fails
-    — plain log lines are not data and are skipped silently."""
-    added = malformed = 0
+    returns the number of malformed lines.  A line counts as malformed
+    only when it *starts* like a JSON record (``{``) and fails — plain
+    log lines are not data and are skipped silently."""
+    malformed = 0
     for line in text.splitlines():
         line = line.strip()
         if not line:
@@ -90,20 +72,17 @@ def _parse_lines(text: str, out: list) -> tuple[int, int]:
             continue
         if isinstance(obj, dict):
             out.append(obj)
-            added += 1
         elif line.startswith(("{", "[")):
             malformed += 1
-    return added, malformed
+    return malformed
 
 
 def split_records(records):
-    """(events, spans, serve, bench, ckpt, unknown) from a mixed record
-    list.  ``ckpt`` holds the durability layer's ``checkpoint_save`` /
+    """(events, spans, serve, ckpt, unknown) from a mixed record list.
+    ``ckpt`` holds the durability layer's ``checkpoint_save`` /
     ``checkpoint_restore`` records (robust/checkpoint.py via
-    obs.events.emit_checkpoint); it is appended AFTER bench so existing
-    positional consumers (compare.py takes [3], slo.py takes [2]) stay
-    valid."""
-    events, spans, serve, bench, ckpt, unknown = [], [], [], [], [], []
+    obs.events.emit_checkpoint); slo.py takes ``serve`` as [2]."""
+    events, spans, serve, ckpt, unknown = [], [], [], [], []
     for r in records:
         schema, kind = r.get("schema"), r.get("kind")
         if schema == EVENT_SCHEMA and kind == "event":
@@ -117,11 +96,9 @@ def split_records(records):
         elif schema == EVENT_SCHEMA and kind in (
                 "checkpoint_save", "checkpoint_restore"):
             ckpt.append(r)
-        elif schema == BENCH_SCHEMA or "metric" in r:
-            bench.append(r)
         else:
             unknown.append(r)
-    return events, spans, serve, bench, ckpt, unknown
+    return events, spans, serve, ckpt, unknown
 
 
 def percentile(values, q: float) -> float | None:
@@ -199,28 +176,6 @@ def summarize_plans(events) -> dict:
                    f"nb={p.get('nb')} source={p.get('source')}")
             table[key] = table.get(key, 0) + 1
     return dict(sorted(table.items(), key=lambda kv: -kv[1]))
-
-
-def summarize_bench(bench) -> dict:
-    """Bench lines -> {metric: {value, unit, chip, ...}} plus skip/error
-    tallies (watchdog skip lines carry phase + elapsed_s)."""
-    metrics: dict[str, dict] = {}
-    skipped, errors = [], []
-    for b in bench:
-        name = b.get("metric") or "?"
-        if b.get("skipped"):
-            skipped.append({"metric": name, "reason": b.get("reason"),
-                            "phase": b.get("phase"),
-                            "elapsed_s": b.get("elapsed_s")})
-            continue
-        if b.get("error"):
-            errors.append({"metric": name, "error": b.get("error")})
-            continue
-        metrics[name] = {k: b[k] for k in
-                         ("value", "unit", "chip", "mfu", "vs_baseline",
-                          "nb", "bw", "kernel", "op", "n")
-                         if k in b and b[k] is not None}
-    return {"metrics": metrics, "skipped": skipped, "errors": errors}
 
 
 def summarize_serve(serve) -> dict:
@@ -347,18 +302,16 @@ def summarize_checkpoint(ckpt) -> dict:
 def summarize(paths) -> dict:
     """Everything the CLI prints, as one JSON-able dict."""
     records, malformed = load_records(paths)
-    events, spans, serve, bench, ckpt, unknown = split_records(records)
+    events, spans, serve, ckpt, unknown = split_records(records)
     return {
         "files": [str(p) for p in paths],
         "counts": {"events": len(events), "spans": len(spans),
-                   "serve": len(serve), "bench": len(bench),
-                   "checkpoint": len(ckpt),
+                   "serve": len(serve), "checkpoint": len(ckpt),
                    "unknown": len(unknown), "malformed": malformed},
         "ops": summarize_events(events),
         "plans": summarize_plans(events),
         "serve": summarize_serve(serve),
         "checkpoint": summarize_checkpoint(ckpt),
-        "bench": summarize_bench(bench),
     }
 
 
@@ -388,8 +341,7 @@ def render(summary: dict) -> str:
     parts = []
     c = summary["counts"]
     parts.append(f"records: {c['events']} events, {c['spans']} spans, "
-                 f"{c.get('serve', 0)} serve batches, "
-                 f"{c['bench']} bench lines"
+                 f"{c.get('serve', 0)} serve batches"
                  + (f", {c['checkpoint']} checkpoint"
                     if c.get("checkpoint") else "")
                  + (f", {c['unknown']} unknown" if c["unknown"] else ""))
@@ -430,20 +382,6 @@ def render(summary: dict) -> str:
         parts.append("\ndurability\n" + _table(
             ["op/kind", "count", "bytes", "wall_p50_ms", "wall_p99_ms",
              "ok", "refused", "refusals"], rows))
-    bench = summary["bench"]
-    if bench["metrics"]:
-        rows = [[m, d.get("value"), d.get("unit"), d.get("mfu"),
-                 d.get("chip")] for m, d in sorted(bench["metrics"].items())]
-        parts.append("\nbench metrics\n" + _table(
-            ["metric", "value", "unit", "mfu", "chip"], rows))
-    if bench["skipped"]:
-        rows = [[s["metric"], s.get("phase"), s.get("elapsed_s"),
-                 s.get("reason")] for s in bench["skipped"]]
-        parts.append("\nbench skipped\n" + _table(
-            ["metric", "phase", "elapsed_s", "reason"], rows))
-    if bench["errors"]:
-        rows = [[e["metric"], e.get("error")] for e in bench["errors"]]
-        parts.append("\nbench errors\n" + _table(["metric", "error"], rows))
     if c.get("malformed"):
         parts.append(f"\nmalformed={c['malformed']} truncated/garbled "
                      f"line(s) skipped")

@@ -240,8 +240,8 @@ def poisson_workload(seed: int, problems: int, rate_hz: float, sizes,
     mixed-size requests with exponential (Poisson-process) inter-arrival
     gaps at ``rate_hz``.  Same seed -> same arrival times, sizes and
     operand values, so overload/shed/quarantine behavior is reproducible
-    on CPU — the chaos harness's load generator (bench_serve_survival
-    and the survival tests replay it).
+    on CPU — the chaos harness's load generator (the survival tests
+    replay it).
 
     Returns ``[(t_arrival_s, op, a, b)]`` sorted by arrival; matrices
     are well-conditioned (diagonally dominated / SPD-shifted), so every

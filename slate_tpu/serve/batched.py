@@ -504,7 +504,7 @@ def _bf16_rung(op: str, a, b, sizes, opts: Options | None):
     escalation target with unchanged code, so a certificate failure
     escalates that problem (and only that problem, via the per-problem
     ``lax.cond``) onto a result bit-identical to the f32-only route.
-    The returned ``escalated`` flags certificate failures: the bench's
+    The returned ``escalated`` flags certificate failures: the rung's
     accept-rate is ``1 - mean(escalated)`` over live slots."""
     plan_lo = _ragged_plan(op, a, opts, dtype=_prec.LOW)
     x1, h1 = BF16_ATTEMPTS[op](a, b, sizes, plan_lo, opts)

@@ -1,12 +1,12 @@
 """Persistent tile-plan store + the trace-time plan resolver.
 
-The r05 bench showed nb=256/512/1024 each winning at different n — the
-panel-kernel choice is a *search* problem per (op, n, dtype, chip), not
-a constant (PAPERS.md "Design in Tiles" / "TileLoom").  This module
-owns the answer: a small on-disk JSON cache of winning ``TilePlan``s,
-written by ``slate_tpu.tune.autotune`` and read back by the internal
-dispatch seams (potrf_tile, getrf panel, geqrf panel) through ONE
-function, :func:`resolve_plan`.
+A pre-benchmark chip round showed nb=256/512/1024 each winning at
+different n — the panel-kernel choice is a *search* problem per (op, n,
+dtype, chip), not a constant (PAPERS.md "Design in Tiles" /
+"TileLoom").  This module owns the answer: a small on-disk JSON cache
+of winning ``TilePlan``s, written by ``slate_tpu.tune.autotune`` and
+read back by the internal dispatch seams (potrf_tile, getrf panel,
+geqrf panel) through ONE function, :func:`resolve_plan`.
 
 Trace-safety contract (slate-lint TRC): ``resolve_plan`` takes only
 host-static values (python ints from ``.shape``, dtype names) and

@@ -2,7 +2,7 @@
 and where entry scripts keep JAX's persistent compilation cache.
 
 Importing the library sets nothing; the entry scripts (chip_smoke.py,
-bench.py, ``python -m slate_tpu.tune``) call :func:`use_compile_cache`.
+``python -m slate_tpu.tune``) call :func:`use_compile_cache`.
 """
 
 from __future__ import annotations

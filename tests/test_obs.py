@@ -15,7 +15,7 @@ Coverage map:
   retrace churn and reports per-op stats;
 - the span tracer records nested phase timings and exports valid
   Chrome trace JSON and span JSONL;
-- metrics: summarize() aggregates event + bench JSONL into per-op
+- metrics: summarize() aggregates event JSONL into per-op
   latency/rate tables and the ``python -m slate_tpu.obs`` CLI renders
   them (text and --json).
 """
@@ -358,25 +358,6 @@ def test_metrics_summarize_events(rng, tmp_path):
     assert s["ops"]["gesv"]["error_rate"] == 0.0
     text = obs.render(s)
     assert "gesv" in text and "p50" in text
-
-
-def test_metrics_summarize_bench_lines(tmp_path):
-    p = tmp_path / "bench.jsonl"
-    lines = [
-        {"schema": "slate-bench-v1", "metric": "gemm_n4096_gflops_per_chip",
-         "value": 123.4, "unit": "GFLOP/s", "chip": "cpu"},
-        {"schema": "slate-bench-v1", "metric": "bench_svd_skipped",
-         "value": None, "skipped": True, "reason": "time budget exceeded "
-         "(watchdog)", "phase": "compile", "elapsed_s": 41.0,
-         "chip": "cpu"},
-        {"metric": "legacy_metric", "value": 7.0},   # pre-schema line
-    ]
-    p.write_text("\n".join(json.dumps(x) for x in lines) + "\n")
-    s = obs.summarize([str(p)])
-    assert s["counts"]["bench"] == 3
-    assert len(s["bench"]["metrics"]) == 2
-    (skip,) = s["bench"]["skipped"]
-    assert skip["phase"] == "compile" and skip["elapsed_s"] == 41.0
 
 
 def test_cli_text_and_json(rng, tmp_path, capsys):

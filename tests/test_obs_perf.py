@@ -1,24 +1,19 @@
-"""Device-time truth tests (obs.flops / obs.slo / obs.compare + the
-timing mode wired through util.trace.annotate and serve/server.py).
+"""Device-time truth tests (obs.flops / obs.slo + the timing mode wired
+through util.trace.annotate and serve/server.py).
 
 The load-bearing guarantees:
 
-- the flops registry prices every public op analytically, and BOTH
-  consumers — timed driver events and bench.py lines — derive mfu from
-  the SAME model (the bench side is asserted in test_bench_smoke.py);
+- the flops registry prices every public op analytically, and timed
+  driver events derive mfu from that model;
 - ``obs.timing()`` stamps ``device_ms`` on the outermost EAGER boundary
   only: traced frames never sync, and the jaxpr is byte-identical with
   timing on or off (the jaxpr-identity guarantee extends to timing);
-- the perf-regression sentinel (``--compare``) classifies the real
-  rounds r04 -> r05 (tests/data: their shared metric lines; all improved,
-  exit 0) and gates the reverse diff (exit 1);
 - SLO budgets evaluate against the serving aggregate with metric-owned
   directions, fail LOUDLY on missing data, and export Prometheus text;
 - malformed/truncated JSONL is counted and reported, never fatal.
 """
 
 import json
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -28,12 +23,8 @@ import pytest
 import slate_tpu as st
 from slate_tpu import obs
 from slate_tpu.obs import __main__ as obs_cli
-from slate_tpu.obs import compare as obs_compare
 from slate_tpu.obs import events as obs_events
 from slate_tpu.obs import flops, metrics, slo
-
-REPO = Path(__file__).resolve().parent.parent
-DATA = Path(__file__).resolve().parent / "data"
 
 
 def _hpd(rng, n=32):
@@ -194,121 +185,6 @@ def test_metrics_aggregate_device_time_columns(rng, tmp_path):
     assert "dev_p50_ms" in text and "mfu" in text
 
 
-# --------------------------------------------- perf-regression sentinel
-
-
-def test_compare_direction_and_noise_model():
-    assert obs_compare.direction("gemm_n4096_gflops_per_chip") == "higher"
-    assert obs_compare.direction("abft_overhead_pct") == "lower"
-    assert obs_compare.direction("serve_latency_p99") == "lower"
-    assert obs_compare.direction("roundtrip", "ms") == "lower"
-    assert obs_compare.noise_pct("serve_mixed_problems_per_s") == 15.0
-    assert obs_compare.noise_pct("sweep_potrf_xla") == 10.0
-    assert obs_compare.noise_pct("gemm_n4096_gflops_per_chip") == \
-        obs_compare.DEFAULT_NOISE_PCT
-    # PERF r15 pipeline metrics ride the wider multi-device noise band,
-    # and the speedup/overlap ratios count as higher-is-better
-    assert obs_compare.noise_pct("summa_lookahead_d1_n8192_gflops") == 10.0
-    assert obs_compare.noise_pct("dist_chol_lookahead_speedup_n16384") == \
-        10.0
-    assert obs_compare.direction("summa_lookahead_overlap_pct_n8192") == \
-        "higher"
-
-
-def _round(tmp_path, name, values):
-    p = tmp_path / name
-    p.write_text("".join(
-        json.dumps({"schema": "slate-bench-v1", "metric": m, "value": v,
-                    "unit": "GFLOP/s", "chip": "cpu"}) + "\n"
-        for m, v in values.items()))
-    return str(p)
-
-
-def test_compare_classifies_and_gates(tmp_path):
-    old = _round(tmp_path, "old.jsonl",
-                 {"gemm": 100.0, "potrf": 100.0, "gone": 1.0})
-    new = _round(tmp_path, "new.jsonl",
-                 {"gemm": 120.0, "potrf": 97.0, "fresh": 2.0})
-    r = obs_compare.compare(old, new)
-    by = {row["metric"]: row for row in r["rows"]}
-    assert by["gemm"]["class"] == "improved" and not by["gemm"]["gated"]
-    assert by["potrf"]["class"] == "flat"     # -3% inside the 5% band
-    assert r["only_old"] == ["gone"] and r["only_new"] == ["fresh"]
-    assert r["regressions"] == []
-
-    # -20% blows through max(gate, noise): regressed AND gated
-    worse = _round(tmp_path, "worse.jsonl", {"gemm": 80.0, "potrf": 99.0})
-    r = obs_compare.compare(old, worse)
-    (bad,) = r["regressions"]
-    assert bad["metric"] == "gemm" and bad["gated"]
-    assert bad["delta_pct"] == -20.0
-
-
-def test_compare_gate_threshold_is_the_ci_knob(tmp_path):
-    """-6% is past the 5% noise band (regressed) but inside the default
-    10% gate — tightening --gate is what turns it into a CI failure."""
-    old = _round(tmp_path, "old.jsonl", {"gemm": 100.0})
-    new = _round(tmp_path, "new.jsonl", {"gemm": 94.0})
-    loose = obs_compare.compare(old, new)
-    assert loose["rows"][0]["class"] == "regressed"
-    assert not loose["regressions"]
-    tight = obs_compare.compare(old, new, gate=5.0)
-    assert tight["regressions"]
-    assert obs_cli.main(["--compare", old, new]) == 0
-    assert obs_cli.main(["--compare", old, new, "--gate", "5"]) == 1
-
-
-def test_compare_noisy_metrics_get_wider_bands(tmp_path):
-    # -12% on a serve metric stays flat (15% band); on a dense metric
-    # it regresses
-    old = _round(tmp_path, "old.jsonl",
-                 {"serve_mixed_problems_per_s": 100.0, "gemm": 100.0})
-    new = _round(tmp_path, "new.jsonl",
-                 {"serve_mixed_problems_per_s": 88.0, "gemm": 88.0})
-    by = {r["metric"]: r for r in obs_compare.compare(old, new)["rows"]}
-    assert by["serve_mixed_problems_per_s"]["class"] == "flat"
-    assert by["gemm"]["class"] == "regressed"
-
-
-def test_compare_lower_better_metrics(tmp_path):
-    old = _round(tmp_path, "old.jsonl", {"abft_overhead_pct": 20.0})
-    new = _round(tmp_path, "new.jsonl", {"abft_overhead_pct": 10.0})
-    (row,) = obs_compare.compare(old, new)["rows"]
-    assert row["better"] == "lower" and row["class"] == "improved"
-    (row,) = obs_compare.compare(new, old)["rows"]
-    assert row["class"] == "regressed" and row["gated"]
-
-
-def test_cli_compare_real_rounds_r04_to_r05(capsys):
-    """The acceptance drill: diff two pre-schema wrapper rounds holding
-    the shared metric lines of the driver's r04 and r05 chip records.
-    Every shared metric improved r04 -> r05, so the gate passes; the
-    reverse diff is 3 gated regressions and exit 1."""
-    r04 = str(DATA / "bench_r04_metrics.json")
-    r05 = str(DATA / "bench_r05_metrics.json")
-    assert obs_cli.main(["--compare", r04, r05]) == 0
-    out = capsys.readouterr().out
-    assert "gemm_n4096_gflops_per_chip" in out
-    assert "improved" in out and "(0 gated)" in out
-
-    assert obs_cli.main(["--compare", r05, r04]) == 1
-    out = capsys.readouterr().out
-    assert "[GATED]" in out and "regressed" in out
-
-
-def test_cli_compare_json_and_missing_file(tmp_path, capsys):
-    r04 = str(DATA / "bench_r04_metrics.json")
-    r05 = str(DATA / "bench_r05_metrics.json")
-    assert obs_cli.main(["--json", "--compare", r04, r05]) == 0
-    doc = json.loads(capsys.readouterr().out)
-    shared = {r["metric"] for r in doc["rows"]}
-    assert {"gemm_n4096_gflops_per_chip", "gemm_n8192_gflops_per_chip",
-            "posv_n16384_gflops_per_chip"} <= shared
-    assert all(r["class"] == "improved" for r in doc["rows"])
-    assert obs_cli.main(["--compare", r04,
-                         str(tmp_path / "nope.json")]) == 2
-
-
 # ------------------------------------------------------------ SLO budgets
 
 
@@ -408,7 +284,7 @@ def test_load_records_counts_truncated_json(tmp_path):
         json.dumps(_serve_rec()) + "\n"
         "INFO some interleaved log line\n"
         '{"schema": "slate-obs-v1", "kind": "event", "op": "ges\n'
-        '{"metric": "gemm", "value": 1.0}\n'
+        '{"schema": "slate-obs-v1", "kind": "event", "op": "gesv"}\n'
         '["a", "json", "array", "line"]\n')
     records, malformed = metrics.load_records([str(p)])
     # truncated dict line counts; the log line does not; the non-dict
@@ -425,17 +301,3 @@ def test_render_omits_malformed_footer_when_clean(tmp_path):
     p = tmp_path / "events.jsonl"
     p.write_text(json.dumps(_serve_rec()) + "\n")
     assert "malformed" not in metrics.render(obs.summarize([str(p)]))
-
-
-def test_load_records_harvests_wrapper_tail(tmp_path):
-    p = tmp_path / "BENCH_rXX.json"
-    p.write_text(json.dumps({
-        "cmd": "python bench.py",
-        "rc": 0,
-        "tail": ("warming up...\n"
-                 '{"schema": "slate-bench-v1", "metric": "gemm", '
-                 '"value": 42.0, "unit": "GFLOP/s"}\n'),
-    }, indent=1))
-    records, malformed = metrics.load_records([str(p)])
-    assert malformed == 0
-    assert [r["metric"] for r in records] == ["gemm"]

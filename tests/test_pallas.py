@@ -1,5 +1,6 @@
 """Pallas TPU kernel tests, run in interpret mode on the CPU backend
-(the real-TPU lowering is exercised by bench.py / the driver rounds)."""
+(the real-TPU lowering is compiled for a described v5e in
+test_tpu_compile.py and run on the chip by chip_smoke.py)."""
 
 import numpy as np
 import jax.numpy as jnp

@@ -505,15 +505,6 @@ def test_cli_serving_table_renders_pool_columns(tmp_path, capsys):
     assert "ladder/float32" in out
 
 
-def test_compare_classifies_pool_metrics():
-    """Pool bench lines get the wide noise band (first-match ordering:
-    'pool' before 'serve') and recovery/latency read lower-better."""
-    from slate_tpu.obs import compare
-    assert compare.noise_pct("serve_pool_problems_per_s") == 20.0
-    assert compare.direction("serve_pool_failover_recovery_ms") == "lower"
-    assert compare.direction("serve_pool_problems_per_s") == "higher"
-
-
 def test_retune_respects_margin_hysteresis():
     """A stream the live ladder already serves well never swaps."""
     rng = _rng()

@@ -625,17 +625,6 @@ def test_cli_serving_table_renders_shed_and_quarantine_columns(
     assert "428.57" in out and " 250 " in out   # _fmt drops trailing .0
 
 
-def test_compare_classifies_survival_metrics():
-    """shed/quar metrics are lower-better and survival lines get the
-    widest noise band (first-match ordering: 'survival' before
-    'serve')."""
-    from slate_tpu.obs import compare
-    assert compare.direction("serve_survival_shed_per_1k") == "lower"
-    assert compare.direction("serve_survival_quar_per_1k") == "lower"
-    assert compare.noise_pct("serve_survival_problems_per_s") == 20.0
-    assert compare.noise_pct("serve_mixed_problems_per_s") == 15.0
-
-
 def test_health_info_reports_front_door_state():
     cfg = serve.AdmissionConfig(slo_budget_ms=250.0)
     srv = serve.Server(cache=serve.ExecutableCache(), admission=cfg)

@@ -1517,7 +1517,7 @@ def test_col008_silent_on_consistent_ring(tmp_path):
 
 
 def test_col008_silent_on_double_buffered_loop_body(tmp_path):
-    # PERF r15 lookahead idiom: the pipeline's loop body ring-shifts BOTH
+    # the lookahead idiom: the pipeline's loop body ring-shifts BOTH
     # the live panel and the in-flight prefetch buffer along the same +1
     # ring — two same-direction hops per axis are one consistent ring
     root = mini_repo(tmp_path, {**COL_GRID, "slate_tpu/mod.py": _col_mod("""\

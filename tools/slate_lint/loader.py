@@ -1,7 +1,7 @@
 """Project loading: discover source files, parse, collect suppressions.
 
-The default scan set is the *checked repo surface*: ``slate_tpu/``,
-``tools/``, and ``bench.py`` under the project root.  ``tests/`` and
+The default scan set is the *checked repo surface*: ``slate_tpu/`` and
+``tools/`` under the project root.  ``tests/`` and
 ``examples/`` are deliberately excluded — rule fixtures live there and
 must be allowed to violate rules on purpose.
 
@@ -18,7 +18,7 @@ from pathlib import Path
 
 from .model import parse_suppressions
 
-DEFAULT_TARGETS = ("slate_tpu", "tools", "bench.py")
+DEFAULT_TARGETS = ("slate_tpu", "tools")
 
 
 class SourceModule:
