@@ -22,6 +22,7 @@ from ..core.grid import Grid
 from ..core.matrix import BaseMatrix, Matrix
 from ..core.storage import TileStorage
 from ..exceptions import slate_error
+from ..obs import events as _obs_events
 from ..options import (MethodGemm, Option, Options, Target, resolve_abft,
                        resolve_target, select_gemm_method)
 from ..parallel import summa
@@ -134,11 +135,14 @@ def trsm(side, alpha, A, B, opts: Options | None = None) -> Matrix:
     work/work_trsm.cc; trsmA variant src/trsmA.cc).
 
     single: one XLA triangular_solve (blocked internally, MXU-shaped).
-    mesh: parallel.dist_trsm substitution pipeline with panel broadcasts.
-    MethodTrsm picks the anchor grid when A and B live on different grids:
-    trsmB (default) moves A's triangle onto B's grid, trsmA keeps A
-    stationary and redistributes B onto A's grid — the reference's
-    stationary-operand distinction (ref: src/trsmA.cc vs src/trsmB.cc).
+    mesh: parallel.dist_trsm substitution pipeline.  MethodTrsm (Auto:
+    trsmA where B is one tile column, else trsmB) names the stationary
+    operand (ref: src/trsmA.cc vs src/trsmB.cc).  Under trsmA a Left
+    solve with B of one tile column keeps every tile of A on its chip
+    and moves only x and the updates; any other solve broadcasts A's
+    panels.  When A and B live on different grids, trsmA redistributes
+    B onto A's grid and trsmB moves A's triangle onto B's.  The event
+    notes the kernel that ran as ``method_trsm``.
     """
     from ..core.matrix import BaseTrapezoidMatrix
     from ..options import MethodTrsm, select_trsm_method
@@ -161,6 +165,7 @@ def trsm(side, alpha, A, B, opts: Options | None = None) -> Matrix:
         lower = A.uplo is Uplo.Lower       # storage triangle
         nb = A.storage.nb
         An = _root_storage_triangular(A, grid=grid)
+        stationary_a = False
         if sd is Side.Right:
             # direct column-substitution kernel: no dense transpose
             Bn = as_root_general(B, None, nb, grid=grid)
@@ -171,10 +176,15 @@ def trsm(side, alpha, A, B, opts: Options | None = None) -> Matrix:
                                    n=An.storage.n)
         else:
             Bn = as_root_general(B, nb, None, grid=grid)
+            stationary_a = (meth is MethodTrsm.trsmA
+                            and Bn.storage.Nt < 2)
             data = dist_trsm_left(An.storage.data, Bn.storage.data,
                                   jnp.asarray(alpha, Bn.dtype),
                                   Nt=An.storage.Nt, grid=grid, lower=lower,
-                                  op_a=A.op, unit_diag=unit, n=An.storage.n)
+                                  op_a=A.op, unit_diag=unit, n=An.storage.n,
+                                  nrhs=Bn.storage.n if stationary_a else None)
+        _obs_events.note_choice("method_trsm",
+                                "trsmA" if stationary_a else "trsmB")
         st = Bn.storage
         return Matrix(TileStorage(data, st.m, st.n, st.mb, st.nb, st.grid))
 

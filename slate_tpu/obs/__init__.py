@@ -25,9 +25,9 @@ returned HealthInfo and host clocks only.
 from . import flops, slo
 from .events import (SCHEMA, boundary_enter, boundary_exit, clear,
                      configure, disable, enable, enabled, emit_serve_batch,
-                     emit_serve_quarantine, emit_serve_shed, note_health,
-                     note_path, note_plan, note_resolved, recent, recording,
-                     set_timing, timing, timing_enabled)
+                     emit_serve_quarantine, emit_serve_shed, note_choice,
+                     note_health, note_path, note_plan, note_resolved,
+                     recent, recording, set_timing, timing, timing_enabled)
 from .metrics import render, summarize
 from .sentinel import SlateRetraceWarning
 from .sentinel import reset as reset_sentinel
@@ -38,7 +38,7 @@ __all__ = [
     "SCHEMA", "SlateRetraceWarning", "SpanRecorder", "boundary_enter",
     "boundary_exit", "clear", "configure", "disable", "enable",
     "enabled", "emit_serve_batch", "emit_serve_quarantine",
-    "emit_serve_shed", "flops", "note_health", "note_path",
+    "emit_serve_shed", "flops", "note_choice", "note_health", "note_path",
     "note_plan", "note_resolved", "recent", "record_spans", "recording",
     "render", "reset_sentinel", "sentinel_stats", "set_timing", "slo",
     "summarize", "timing", "timing_enabled",

@@ -314,6 +314,7 @@ def _build(frame: _Frame, error) -> dict:
         "escalations": notes.get("escalations", 0),
         "health": notes.get("health"),
         "plans": notes.get("plans", []),
+        "method_trsm": notes.get("method_trsm"),
         "status": ("ok" if error is None
                    else f"error:{type(error).__name__}"),
     }
@@ -450,6 +451,15 @@ def note_resolved(knob: str, value) -> None:
     frame = _outer()
     if frame is not None:
         frame.notes.setdefault(knob, bool(value))
+
+
+def note_choice(knob: str, name: str) -> None:
+    """Called by a driver that picks among kernels (trsm's
+    ``method_trsm``): record the name of the one that ran, first write
+    wins like :func:`note_resolved`."""
+    frame = _outer()
+    if frame is not None:
+        frame.notes.setdefault(knob, str(name))
 
 
 def note_path(first: str, rungs, used: int, speculated: bool) -> None:

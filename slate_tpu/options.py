@@ -167,8 +167,8 @@ class MethodTrsm(enum.Enum):
     """trsm variant (ref: method.hh:25-74)."""
 
     Auto = "auto"
-    trsmA = "trsmA"  # stationary A
-    trsmB = "trsmB"  # stationary B; default
+    trsmA = "trsmA"  # stationary A; Auto's pick for one block column of B
+    trsmB = "trsmB"  # stationary B; Auto's pick for a wider B
 
 
 class MethodHemm(enum.Enum):
@@ -349,11 +349,12 @@ def select_gemm_method(opts: Options | None, nt: int) -> MethodGemm:
 
 
 def select_trsm_method(opts: Options | None, nt: int) -> MethodTrsm:
-    """ref: method.hh:56-74 — trsmA for very wide RHS stays with A; default B."""
+    """ref: method.hh:56-74 — trsmA when B is a single block column, else
+    trsmB; an explicit method is honoured."""
     m = get_option(opts, Option.MethodTrsm)
     if m is not MethodTrsm.Auto:
         return m
-    return MethodTrsm.trsmB
+    return MethodTrsm.trsmA if nt < 2 else MethodTrsm.trsmB
 
 
 def select_gels_method(opts: Options | None, m: int, n: int) -> MethodGels:

@@ -2,6 +2,8 @@
 single device and 2x2 / 2x4 meshes (analog of ref test/test_posv.cc,
 test_potrf.cc residual methodology: ||Ax-b|| / (||A|| ||x|| n)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -76,6 +78,97 @@ def test_trsm_left(rng, uplo, op, target):
     eff = tri.T if op == "t" else tri
     np.testing.assert_allclose(eff @ X.to_numpy(), 2.0 * b,
                                rtol=1e-10, atol=1e-10)
+
+
+@pytest.mark.parametrize("uplo,op,dtype", [
+    ("lower", "n", np.float64), ("lower", "t", np.float64),
+    ("upper", "n", np.float64), ("upper", "t", np.float64),
+    ("lower", "c", np.complex128)])
+@pytest.mark.parametrize("p,q", [(2, 2), (2, 4)])
+@pytest.mark.parametrize("n,nb", [(24, 4), (18, 5)])
+@pytest.mark.parametrize("wide", [False, True], ids=["nrhs1", "nrhs_nb"])
+def test_trsm_left_stationary_a(rng, uplo, op, dtype, p, q, n, nb, wide):
+    """B of one tile column (nrhs 1 or nb) runs the stationary-A kernel
+    under Auto: op(A) X = alpha B against numpy, and X equal to the
+    stationary-B kernel's, forced with MethodTrsm.trsmB."""
+    nrhs = nb if wide else 1
+    lower = uplo == "lower"
+    a = rng.standard_normal((n, n)) + n * np.eye(n)
+    b = rng.standard_normal((n, nrhs))
+    if np.issubdtype(dtype, np.complexfloating):
+        a = a + 1j * rng.standard_normal((n, n))
+        b = b + 1j * rng.standard_normal((n, nrhs))
+    tri = np.tril(a) if lower else np.triu(a)
+    g = st.Grid(p, q, devices=jax.devices()[: p * q])
+    A = st.TriangularMatrix.from_numpy(
+        a, nb, st.Uplo.Lower if lower else st.Uplo.Upper, grid=g)
+    A = {"n": A, "t": A.transpose(), "c": A.conj_transpose()}[op]
+    eff = {"n": tri, "t": tri.T, "c": tri.conj().T}[op]
+    B = st.Matrix.from_numpy(b, nb, nb, g)
+    x = st.trsm("l", 2.0, A, B).to_numpy()
+    np.testing.assert_allclose(eff @ x, 2.0 * b, rtol=1e-10, atol=1e-10)
+    xb = st.trsm("l", 2.0, A, B,
+                 {st.Option.MethodTrsm: st.MethodTrsm.trsmB}).to_numpy()
+    np.testing.assert_allclose(x, xb, rtol=1e-12, atol=1e-12 * abs(xb).max())
+
+
+def _all_reduce_sizes(text):
+    """Element count of every array each all-reduce of an HLO text moves
+    (a combined all-reduce moves a tuple)."""
+    sizes = []
+    for line in text.splitlines():
+        m = re.search(r"= (.*?) all-reduce(?:-start)?\(", line)
+        if m:
+            sizes.append([int(np.prod([int(d) for d in dims.split(",")
+                                       if d] or [1]))
+                          for dims in re.findall(r"\w+\[([\d,]*)\]",
+                                                 m[1])])
+    return sizes
+
+
+@pytest.mark.parametrize("nrhs,method,stationary_a", [
+    (1, st.MethodTrsm.Auto, True),
+    (48, st.MethodTrsm.Auto, False),
+    (48, st.MethodTrsm.trsmA, False)])
+def test_trsm_stationary_a_moves_no_tile_of_a(rng, nrhs, method,
+                                              stationary_a):
+    """dist_trsm_left as the driver lowers it on Grid(2, 2), n=64,
+    nb=16: with one tile column of B no all-reduce of the compiled
+    program carries as many as nb*nb entries (no tile of A leaves its
+    chip); a B of three tile columns keeps the stationary-B kernel,
+    whose panel all-reduce does, under either method."""
+    n, nb = 64, 16
+    g = st.Grid(2, 2, devices=jax.devices()[:4])
+    A = st.TriangularMatrix.from_numpy(
+        rng.standard_normal((n, n)) + n * np.eye(n), nb, st.Uplo.Lower,
+        grid=g)
+    B = st.Matrix.from_numpy(rng.standard_normal((n, nrhs)), nb, nb, g)
+    opts = {st.Option.MethodTrsm: method}
+    text = jax.jit(lambda A, B: st.trsm("l", 1.0, A, B, opts)).lower(
+        A, B).compile().as_text()
+    sizes = _all_reduce_sizes(text)
+    assert sizes
+    largest = max(max(s) for s in sizes)
+    if stationary_a:
+        assert largest < nb * nb, sizes
+    else:
+        assert largest >= nb * nb, sizes
+
+
+def test_posv_mesh_one_rhs(rng):
+    """posv on Grid(2, 2) with one right-hand side: both sweeps take the
+    stationary-A kernel, end to end."""
+    n, nb = 24, 4
+    g = st.Grid(2, 2, devices=jax.devices()[:4])
+    a = spd(rng, n)
+    b = rng.standard_normal((n, 1))
+    A = st.HermitianMatrix.from_numpy(a, nb, st.Uplo.Lower, g)
+    L, X = st.posv(A, st.Matrix.from_numpy(b, nb, nb, g))
+    x = X.to_numpy()
+    assert x.shape == (n, 1)
+    resid = np.linalg.norm(a @ x - b) / (
+        np.linalg.norm(a) * np.linalg.norm(x) * n)
+    assert resid < 1e-15
 
 
 @pytest.mark.parametrize("target", ["single", "mesh"])

@@ -125,3 +125,39 @@ def test_every_option_member_consumed():
                if f"Option.{m.name}" not in src
                and accessor.get(m.name, "\x00") not in src]
     assert not missing, f"inert options: {missing}"
+
+
+@pytest.mark.parametrize("method,nt,picked", [
+    (st.MethodTrsm.Auto, 1, st.MethodTrsm.trsmA),
+    (st.MethodTrsm.Auto, 2, st.MethodTrsm.trsmB),
+    (st.MethodTrsm.Auto, 5, st.MethodTrsm.trsmB),
+    (st.MethodTrsm.trsmB, 1, st.MethodTrsm.trsmB),
+    (st.MethodTrsm.trsmA, 5, st.MethodTrsm.trsmA)])
+def test_select_trsm_method(method, nt, picked):
+    # Auto: stationary A for one block column of B, else stationary B
+    # (ref: method.hh); an explicit method is honoured
+    from slate_tpu.options import select_trsm_method
+    assert select_trsm_method({Option.MethodTrsm: method}, nt) is picked
+
+
+@pytest.mark.parametrize("ncols,method,kernel", [
+    (1, st.MethodTrsm.Auto, "trsmA"),
+    (4, st.MethodTrsm.Auto, "trsmA"),
+    (12, st.MethodTrsm.Auto, "trsmB"),
+    (1, st.MethodTrsm.trsmB, "trsmB"),
+    (12, st.MethodTrsm.trsmA, "trsmB")])
+def test_trsm_event_records_kernel(rng, ncols, method, kernel):
+    # the mesh trsm boundary's event names the kernel that ran: the
+    # stationary-A one serves B of one tile column only
+    from slate_tpu import obs
+    n, nb = 16, 4
+    g = st.Grid(2, 2, devices=jax.devices()[:4])
+    a = np.tril(rng.standard_normal((n, n))) + n * np.eye(n)
+    b = rng.standard_normal((n, ncols))
+    A = st.TriangularMatrix.from_numpy(a, nb, st.Uplo.Lower, grid=g)
+    with obs.recording() as ev:
+        X = st.trsm("l", 1.0, A, st.Matrix.from_numpy(b, nb, nb, g),
+                    {Option.MethodTrsm: method})
+    (e,) = ev
+    assert e["op"] == "trsm" and e["method_trsm"] == kernel
+    np.testing.assert_allclose(a @ X.to_numpy(), b, atol=1e-10)
